@@ -33,6 +33,7 @@ import json, time
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.exchanger import get_exchanger
+from repro.launch.mesh import make_mesh
 from repro.roofline.analysis import parse_collectives
 
 MODELS = {
@@ -46,7 +47,7 @@ MODELS = {
 
 mname = sys.argv[1]
 shapes = MODELS[mname]
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 jax.set_mesh(mesh)
 key = jax.random.key(0)
 rows = []

@@ -22,6 +22,7 @@ def run():
     from repro.configs import get_config
     from repro.dist.sharding import (param_spec, param_shardings,
                                      sanitize_spec, state_shardings)
+    from repro.launch.mesh import make_mesh
     from repro.launch.specs import abstract_state
     from repro.models import build_model
     from repro.optim import sgd_momentum
@@ -44,7 +45,7 @@ def run():
                  f"leaves={len(leaves)};us_per_leaf={us / len(leaves):.1f}"))
 
     # full builders need a real (1-device) mesh for NamedSharding
-    rmesh = jax.make_mesh((1, 1), ("data", "model"))
+    rmesh = make_mesh((1, 1), ("data", "model"))
     us = _time(lambda: param_shardings(rmesh, params))
     rows.append(("dist/param_shardings_123b", us, f"leaves={len(leaves)}"))
 

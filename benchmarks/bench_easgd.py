@@ -22,6 +22,7 @@ import json, time
 import jax, numpy as np
 from repro.configs import get_smoke_config
 from repro.data.synthetic import LMTokenSource
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import constant, sgd_momentum
 from repro.train.engine import TrainPlan, build_engine
@@ -29,7 +30,7 @@ from repro.train.engine import TrainPlan, build_engine
 cfg = get_smoke_config("llama3.2-1b").with_overrides(vocab_size=128)
 model = build_model(cfg)
 opt = sgd_momentum(weight_decay=0.0)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 jax.set_mesh(mesh)
 src = LMTokenSource(cfg.vocab_size, 32)
 B = 32

@@ -31,6 +31,7 @@ QUICK = %(quick)d
 import json, time
 import jax, numpy as np
 from repro import telemetry
+from repro.launch.mesh import make_mesh
 from repro.telemetry import trace
 from repro.configs import get_smoke_config
 from repro.data.synthetic import LMTokenSource
@@ -49,7 +50,7 @@ short, long = (2 * tau, 6 * tau) if QUICK else (4 * tau, 12 * tau)
 rows = []
 
 # fixed-engine reference: same algo/tau, warmed, no membership machinery
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 jax.set_mesh(mesh)
 plan_f = TrainPlan(algo="easgd", exchanger="ar", tau=tau, alpha=0.5)
 eng = build_engine(plan_f, model, opt, constant(0.02), mesh)

@@ -7,6 +7,8 @@ overlap efficiency (parallel/sync throughput; >1 means the loader hid IO).
 import tempfile
 import time
 
+from repro.launch.mesh import make_mesh
+
 
 def run():
     import jax
@@ -21,7 +23,7 @@ def run():
     cfg = get_smoke_config("alexnet")
     model = build_model(cfg)
     opt = sgd_momentum(weight_decay=0.0)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     step = jax.jit(make_bsp_step(model, opt, get_exchanger("ar"),
                                  constant(0.01), mesh))

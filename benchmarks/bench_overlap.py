@@ -35,6 +35,7 @@ import json, time
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import (get_exchanger, init_sharded_train_state,
                         init_train_state, make_bsp_step)
+from repro.launch.mesh import make_mesh
 from repro.models.registry import Model
 from repro.optim import constant, sgd_momentum
 from repro.roofline.analysis import overlap_evidence, parse_collectives
@@ -70,7 +71,7 @@ def build_model():
 
 
 model = build_model()
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 jax.set_mesh(mesh)
 opt = sgd_momentum(weight_decay=0.0)
 batch = {"x": np.random.default_rng(0).normal(

@@ -17,6 +17,7 @@ import jax, numpy as np
 from repro.configs import get_smoke_config
 from repro.core import get_exchanger, init_train_state, make_bsp_step
 from repro.data.synthetic import ImageSource, LMTokenSource
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import constant, sgd_momentum
 
@@ -28,7 +29,7 @@ for arch in ["alexnet", "llama3.2-1b"]:
     per_worker = 8
     base = None
     for k in [1, 2, 4, 8]:
-        mesh = jax.make_mesh((k,), ("data",),
+        mesh = make_mesh((k,), ("data",),
                              devices=np.array(jax.devices()[:k]))
         jax.set_mesh(mesh)
         step = jax.jit(make_bsp_step(model, opt, get_exchanger("asa"),

@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.data.synthetic import LMTokenSource
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import constant, sgd_momentum
 from repro.train.engine import TrainPlan
@@ -33,7 +34,7 @@ def main():
     cfg = get_smoke_config("llama3.2-1b").with_overrides(vocab_size=256)
     model = build_model(cfg)
     k = len(jax.devices())
-    mesh = jax.make_mesh((k,), ("data",))
+    mesh = make_mesh((k,), ("data",))
     jax.set_mesh(mesh)
     src = LMTokenSource(cfg.vocab_size, 64)
     opt = sgd_momentum(weight_decay=0.0)

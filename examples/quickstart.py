@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_smoke_config
 from repro.data.synthetic import LMTokenSource
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import sgd_momentum, warmup_cosine
 from repro.train.loop import train
@@ -17,7 +18,7 @@ from repro.train.serve import generate
 def main():
     cfg = get_smoke_config("llama3.2-1b").with_overrides(vocab_size=256)
     model = build_model(cfg)
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     jax.set_mesh(mesh)
 
     src = LMTokenSource(cfg.vocab_size, seq_len=64)

@@ -15,6 +15,7 @@ import jax
 from repro.configs import get_config, get_smoke_config
 from repro.data.prefetch import ParallelLoader
 from repro.data.synthetic import ImageSource, materialize_batch_files
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, count_params
 from repro.optim import sgd_momentum, step_decay
 from repro.train.loop import train
@@ -37,7 +38,7 @@ def main():
     print(f"AlexNet ({'full' if args.full else 'reduced'}): {n:,} params, "
           f"exchanger={args.exchanger}, scheme={args.scheme}")
 
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     jax.set_mesh(mesh)
 
     with tempfile.TemporaryDirectory() as td:

@@ -15,6 +15,7 @@ import jax
 from repro.configs import get_config
 from repro.data.prefetch import ParallelLoader
 from repro.data.synthetic import LMTokenSource, materialize_batch_files
+from repro.launch.mesh import make_mesh
 from repro.models import build_model, count_params
 from repro.optim import sgd_momentum, warmup_cosine
 from repro.train.loop import train
@@ -39,7 +40,7 @@ def main():
           f"{count_params(jax.eval_shape(model.init, jax.random.key(0))):,}"
           " params")
 
-    mesh = jax.make_mesh((len(jax.devices()),), ("data",))
+    mesh = make_mesh((len(jax.devices()),), ("data",))
     jax.set_mesh(mesh)
 
     with tempfile.TemporaryDirectory() as td:

@@ -1,8 +1,1 @@
-"""repro: a JAX reproduction of Theano-MPI grown toward production scale.
-
-Importing any ``repro.*`` module installs the jax API compat shims (see
-``repro._compat``) so the rest of the codebase can target one API surface.
-"""
-from repro import _compat as _compat
-
-_compat.install()
+"""repro: a JAX reproduction of Theano-MPI grown toward production scale."""

@@ -38,11 +38,11 @@ whole and updated replicated — chunking overhead dominates there.
 
 NOTE: flattening assumes gradient leaves are *replicated* over any
 model-parallel mesh axes inside the shard_map body — the invariant the
-BSP path maintains (``repro.dist.state_shardings`` replicates train state;
-on jax 0.4.x shard_map is fully manual, see ``repro/_compat.py``). Under
-a future partial-auto shard_map with model-sharded gradient leaves, the
-reshape/concat would force GSPMD to regather each leaf — the GSPMD/ZeRO-1
-path (``core/gspmd.py``) is the right tool there, not this module.
+BSP path maintains (``repro.dist.state_shardings`` replicates train
+state). With model-sharded gradient leaves under the partial-auto
+shard_map, the reshape/concat would force GSPMD to regather each leaf —
+the GSPMD/ZeRO-1 path (``core/gspmd.py``) is the right tool there, not
+this module.
 
 Every strategy computes the *mean* over the data axes and is numerically
 interchangeable (up to its transfer precision) — property-tested in

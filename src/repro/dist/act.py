@@ -51,9 +51,8 @@ def _bound_axes():
     """Mesh axes currently bound as *manual* (shard_map) axes at trace time.
 
     A with_sharding_constraint may only reference auto axes; entries naming
-    manual axes must drop. Under the 0.4.x fully-manual BSP shard_map every
-    axis is bound, so the constraint degenerates to the identity there —
-    jax>=0.5 partial shard_map leaves 'model' auto and keeps it."""
+    manual axes must drop. The BSP shard_map binds only the data axes, so
+    'model' stays auto and its constraints are kept."""
     try:
         from jax._src import core as jcore
         return frozenset(jcore.get_axis_env().axis_names())

@@ -239,10 +239,7 @@ def state_shardings(mesh, state):
 
     Parameters and optimizer state are replicated over the WHOLE mesh (the
     exchangers own the data axes as shard_map manual axes; the model axis
-    contributes through activation constraints only). Replication is also a
-    hard requirement on jaxlib 0.4.x: its SPMD partitioner aborts when a
-    manual-subgroup collective (the exchanger's all_to_all/all_gather over
-    'data') consumes an operand sharded on an auto axis. Architectures too
+    contributes through activation constraints only). Architectures too
     big to replicate take the GSPMD/ZeRO-1 path (``fsdp_state_shardings``),
     selected by the FSDP threshold in ``launch/dryrun.py``."""
     rep = NamedSharding(mesh, P())
@@ -267,7 +264,7 @@ def cache_shardings(mesh, cache, global_batch: int,
     recurrent state); conv windows and rope keys replicated.
 
     ``page_batch``: page count of a paged serve pool — attention leaves
-    there carry (layers, num_pages, page_size, ...) instead of a slot
+    there carry (layers, num_pages, ...) pages instead of a slot
     batch dim, and the page dim shards over the data axes exactly like the
     slot dim does (pages are the unit of cache parallelism)."""
     dpe = _dp_entry(mesh)
@@ -284,7 +281,9 @@ def cache_shardings(mesh, cache, global_batch: int,
                     break
         if not _REPLICATE_ATTN:
             mi = None
-            if key in ("k", "v") and l.ndim >= 2:
+            if key in ("k", "v") and page_batch is not None:
+                mi = l.ndim - 3          # (..., P, KV, ps, hd): KV heads
+            elif key in ("k", "v") and l.ndim >= 2:
                 mi = l.ndim - 2          # (..., S, KV, hd): KV heads
             elif key == "ckv":
                 mi = l.ndim - 1          # (..., S, R): MLA latent

@@ -6,7 +6,7 @@
 Every kernel takes ``interpret: bool | None``; ``None`` (the default)
 resolves via :func:`default_interpret` — compiled on TPU, the Pallas
 interpreter elsewhere — overridable per-process with
-``REPRO_PALLAS_INTERPRET=0|1`` (or the legacy ``REPRO_PALLAS_COMPILED=1``).
+``REPRO_PALLAS_INTERPRET=0|1``.
 """
 from __future__ import annotations
 
@@ -16,14 +16,12 @@ import os
 def default_interpret() -> bool:
     """Whether Pallas kernels should run in interpreter mode.
 
-    Priority: ``REPRO_PALLAS_INTERPRET`` env (0/1) > legacy
-    ``REPRO_PALLAS_COMPILED=1`` > backend autodetect (compiled only on
-    TPU — the interpreter is the only Pallas path on CPU hosts)."""
+    Priority: ``REPRO_PALLAS_INTERPRET`` env (0/1) > backend autodetect
+    (compiled only on TPU — the interpreter is the only Pallas path on CPU
+    hosts)."""
     env = os.environ.get("REPRO_PALLAS_INTERPRET")
     if env is not None:
         return env not in ("0", "false", "False")
-    if os.environ.get("REPRO_PALLAS_COMPILED", "0") == "1":
-        return False
     import jax
     return jax.default_backend() != "tpu"
 

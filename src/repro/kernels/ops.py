@@ -2,7 +2,7 @@
 
 Execution mode is auto-selected per backend (compiled on TPU, Pallas
 interpreter elsewhere) — see ``repro.kernels.default_interpret`` for the
-``REPRO_PALLAS_INTERPRET`` / legacy ``REPRO_PALLAS_COMPILED`` overrides.
+``REPRO_PALLAS_INTERPRET`` override.
 The wrappers match the exchanger/optimizer plug-in contracts.
 """
 from __future__ import annotations

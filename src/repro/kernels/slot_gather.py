@@ -48,12 +48,10 @@ def _kernel(lg_ref, oh_ref, t_ref, nz_ref, gv_ref, gi_ref, sv_ref, si_ref,
     lg = lg_ref[...].astype(jnp.float32)            # (S, C, bv)
     oh = oh_ref[...]                                # (S, C) one-hot fp32
     row = jnp.sum(lg * oh[..., None], axis=1)       # (S, bv) gathered rows
-    idx = jax.lax.broadcasted_iota(jnp.int32, row.shape, 1) + i * block_v
 
     def fold(vals, bv_ref, bi_ref):
         m = jnp.max(vals, axis=1)
-        am = jnp.argmax(vals, axis=1).astype(jnp.int32)
-        gidx = jnp.take_along_axis(idx, am[:, None], axis=1)[:, 0]
+        gidx = jnp.argmax(vals, axis=1).astype(jnp.int32) + i * block_v
         better = m > bv_ref[...]                    # strict: first tile wins
         bi_ref[...] = jnp.where(better, gidx, bi_ref[...])
         bv_ref[...] = jnp.where(better, m, bv_ref[...])

@@ -6,8 +6,7 @@ production meshes and record memory/cost/collective analysis.
 
 The XLA_FLAGS assignment below MUST run before jax initializes a backend
 (the host device count locks at first backend init, not at import — merely
-importing jax, as ``repro/__init__``'s compat shims do, is safe; touching
-``jax.devices()`` earlier is not). Do not import this module from processes
+importing jax is safe; touching ``jax.devices()`` earlier is not). Do not import this module from processes
 that need 1 device.
 """
 import os

@@ -29,6 +29,7 @@ import numpy as np
 from repro import telemetry
 from repro.configs import get_smoke_config, get_config
 from repro.configs.base import with_attn_impl
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import build_model
 from repro.serve import Engine, SamplingParams
 from repro.train.serve import generate
@@ -112,6 +113,7 @@ def main():
                          "(profile/* and compile/* gauges); same as "
                          "REPRO_TELEMETRY_PROFILE=0")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.no_profile:
         telemetry.configure(profile=False)
 

@@ -35,6 +35,7 @@ import numpy as np
 from repro import telemetry
 from repro.configs import get_config, get_smoke_config
 from repro.configs.base import with_attn_impl
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data.synthetic import LMTokenSource, ImageSource
 from repro.launch.mesh import make_host_mesh
 from repro.models import build_model
@@ -134,6 +135,7 @@ def main():
                          "(profile/* and compile/* gauges); same as "
                          "REPRO_TELEMETRY_PROFILE=0")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.metrics_out:
         telemetry.configure(metrics_out=args.metrics_out)

@@ -181,17 +181,23 @@ def _page_coords(pos, page_size: int, num_logical: int):
     return jnp.clip(pos // page_size, 0, num_logical - 1), pos % page_size
 
 
-def _scatter_page_rows(buf, new, tables, pos_vec, page_size: int):
+def _scatter_page_rows(buf, new, tables, pos_vec, page_size: int,
+                       head_major: bool = False):
     """Write one (B, 1, ...) row per batch element into the paged buffer
-    (P, page_size, ...) through the block table (B, NP). Idle slots map
-    to the null page; their duplicate writes land there harmlessly."""
+    through the block table (B, NP). Idle slots map to the null page;
+    their duplicate writes land there harmlessly. ``head_major`` pages are
+    (P, KV, page_size, D) (GQA), otherwise (P, page_size, ...) (MLA)."""
     B = new.shape[0]
     pj, pr = _page_coords(pos_vec, page_size, tables.shape[1])
     pid = tables[jnp.arange(B), pj]
-    return buf.at[pid, pr].set(new[:, 0].astype(buf.dtype))
+    rows = new[:, 0].astype(buf.dtype)
+    if head_major:
+        return buf.at[pid, :, pr].set(rows)
+    return buf.at[pid, pr].set(rows)
 
 
-def _scatter_chunk_rows(buf, new, tables, positions, page_size: int):
+def _scatter_chunk_rows(buf, new, tables, positions, page_size: int,
+                        head_major: bool = False):
     """Scatter a (B, C, ...) prefill chunk into the paged buffer through
     each row's block table. ``positions`` (B, C) absolute — any alignment
     (prefix-cache resume starts mid-stream); rows whose page the table
@@ -199,17 +205,21 @@ def _scatter_chunk_rows(buf, new, tables, positions, page_size: int):
     contract the contiguous path has beyond ``valid``."""
     B, C = new.shape[:2]
     pj, pr = _page_coords(positions, page_size, tables.shape[1])
-    pid = jnp.take_along_axis(tables, pj, axis=1)            # (B, C)
+    pid = jnp.take_along_axis(tables, pj, axis=1).reshape(-1)  # (B*C,)
     flat = new.reshape((B * C,) + new.shape[2:]).astype(buf.dtype)
-    return buf.at[pid.reshape(-1), pr.reshape(-1)].set(flat)
+    if head_major:
+        return buf.at[pid, :, pr.reshape(-1)].set(flat)
+    return buf.at[pid, pr.reshape(-1)].set(flat)
 
 
-def _gather_lane(buf, tables):
+def _gather_lane(buf, tables, head_major: bool = False):
     """(B, NP*page_size, ...) virtual contiguous lanes gathered from the
     paged buffer — the ref-impl read path (bit-identical rows to a
     contiguous pool lane wherever the lane was actually written)."""
-    pages = buf[tables]                                      # (B, NP, ps, ...)
-    return pages.reshape((tables.shape[0], -1) + buf.shape[2:])
+    pages = buf[tables]                           # (B, NP, [KV,] ps, ...)
+    if head_major:
+        pages = pages.swapaxes(2, 3)              # (B, NP, ps, KV, D)
+    return pages.reshape((tables.shape[0], -1) + pages.shape[3:])
 
 
 def _masked_softmax(scores, keep):
@@ -353,13 +363,22 @@ def gqa_init_cache(batch: int, max_len: int, a: AttentionConfig, dtype):
     }
 
 
+def gqa_init_pages(num_pages: int, page_size: int, a: AttentionConfig,
+                   dtype):
+    """Physical KV pages, head-major (P, KV, page_size, hd): one page of
+    one kv head is a (page_size, hd) tile, the block ``flash_decode_paged``
+    fetches."""
+    shape = (num_pages, a.num_kv_heads, page_size, a.head_dim)
+    return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
+
+
 def gqa_decode(p, cache, x, pos, a: AttentionConfig, window: int,
                impl: str | None = None, tables=None, page_size: int = 0):
     """One-token decode. x:(B,1,d); pos: scalar int (current index) or a
     (B,) vector of per-sequence indices (serving engine slots).
 
     ``tables`` (B, NP) int32 switches the cache to the paged layout
-    (cache leaves are (P, page_size, ...) physical pages): the new row
+    (cache leaves are (P, KV, page_size, hd) physical pages): the new row
     scatters through the table, flash reads fetch pages tile-wise inside
     ``flash_decode_paged``, and the ref path gathers the virtual lane —
     identical math to the contiguous layout on the gathered rows.
@@ -375,13 +394,14 @@ def gqa_decode(p, cache, x, pos, a: AttentionConfig, window: int,
     B = x.shape[0]
     if tables is not None:
         pv = posv[:, 0]
-        ck = _scatter_page_rows(cache["k"], k, tables, pv, page_size)
-        cv = _scatter_page_rows(cache["v"], v, tables, pv, page_size)
+        ck = _scatter_page_rows(cache["k"], k, tables, pv, page_size, True)
+        cv = _scatter_page_rows(cache["v"], v, tables, pv, page_size, True)
         if impl == "flash":
             out = flash_decode_paged(q, ck, cv, tables, pv,
                                      page_size=page_size, window=window)
         else:
-            lk, lv = _gather_lane(ck, tables), _gather_lane(cv, tables)
+            lk = _gather_lane(ck, tables, True)
+            lv = _gather_lane(cv, tables, True)
             keep = decode_keep_batched(jnp.arange(lk.shape[1]), pv,
                                        window)[:, None, :]
             out = gqa_attend(q, lk, lv, keep, a)
@@ -427,9 +447,12 @@ def gqa_prefill(p, cache, x, positions, pos0, a: AttentionConfig,
     k = apply_rope(k, positions, a.rope_theta)
     B, C = x.shape[:2]
     if tables is not None:
-        ck = _scatter_chunk_rows(cache["k"], k, tables, positions, page_size)
-        cv = _scatter_chunk_rows(cache["v"], v, tables, positions, page_size)
-        lane_k, lane_v = _gather_lane(ck, tables), _gather_lane(cv, tables)
+        ck = _scatter_chunk_rows(cache["k"], k, tables, positions,
+                                 page_size, True)
+        cv = _scatter_chunk_rows(cache["v"], v, tables, positions,
+                                 page_size, True)
+        lane_k = _gather_lane(ck, tables, True)
+        lane_v = _gather_lane(cv, tables, True)
     else:
         ck = jax.lax.dynamic_update_slice_in_dim(
             cache["k"], k.astype(cache["k"].dtype), pos0, axis=1)
@@ -619,6 +642,16 @@ def attn_init_cache(batch: int, max_len: int, cfg: ArchConfig, dtype):
     if a.kv_lora_rank:
         return mla_init_cache(batch, max_len, a, dtype)
     return gqa_init_cache(batch, max_len, a, dtype)
+
+
+def attn_init_pages(num_pages: int, page_size: int, cfg: ArchConfig,
+                    dtype):
+    """Paged pool leaves: GQA pages head-major, MLA latent/rope-key pages
+    (P, page_size, R/rope) — already (rows, dim) tiles."""
+    a = cfg.attention
+    if a.kv_lora_rank:
+        return mla_init_cache(num_pages, page_size, a, dtype)
+    return gqa_init_pages(num_pages, page_size, a, dtype)
 
 
 def attn_decode(p, cache, x, pos, cfg: ArchConfig, window: int,

@@ -366,9 +366,10 @@ def init_decoder_cache(cfg: ArchConfig, batch: int, max_len: int):
 def init_paged_decoder_cache(cfg: ArchConfig, max_slots: int,
                              page_size: int, num_pages: int):
     """Paged cache pool mirroring the segment structure: attention leaves
-    hold (count, num_pages, page_size, ...) physical pages shared by every
-    slot through a block table, SSM conv/state leaves keep one lane per
-    slot (count, max_slots, ...) — they have no sequence dim to page. The
+    hold (count, num_pages, ...) physical pages (``attn_init_pages``)
+    shared by every slot through a block table, SSM conv/state leaves
+    keep one lane per slot (count, max_slots, ...) — they have no
+    sequence dim to page. The
     meta/VLM row padding of ``init_decoder_cache`` does not apply: the
     serve paths never write meta prefixes, and pages are allocated by
     demand, not worst case."""
@@ -377,7 +378,7 @@ def init_paged_decoder_cache(cfg: ArchConfig, max_slots: int,
     for kind, count in segments(cfg):
         one = {}
         if kind in ("dense", "moe", "hybrid"):
-            one["attn"] = attn_mod.attn_init_cache(num_pages, page_size,
+            one["attn"] = attn_mod.attn_init_pages(num_pages, page_size,
                                                    cfg, dtype)
         if kind in ("ssm", "hybrid"):
             one["ssm"] = ssm_mod.ssm_init_cache(max_slots, cfg.d_model,

@@ -1,6 +1,6 @@
 """Roofline analysis from compiled dry-run artifacts.
 
-Terms (per device, TPU v5e constants):
+Terms (per device, TPU v5e peaks from ``PEAKS``):
     compute    = HLO_flops / PEAK_FLOPS
     memory     = HLO_bytes / HBM_BW
     collective = collective_bytes / ICI_BW
@@ -17,27 +17,43 @@ import os
 import re
 from dataclasses import dataclass, field
 
-# TPU v5e
-PEAK_FLOPS = 197e12      # bf16 FLOP/s per chip
-HBM_BW = 819e9           # B/s
-ICI_BW = 50e9            # B/s per link
+# Per-chip peaks keyed by ``jax.Device.device_kind``; FLOP/s and B/s.
+# "TPU v5 lite" is TPU v5e. Source: Google Cloud documentation, "TPU v5e"
+# (cloud.google.com/tpu/docs/v5e): 197 TFLOP/s bf16, 16 GB HBM at
+# 819 GB/s, 1,600 Gbit/s of inter-chip interconnect over 4 links
+# (50 GB/s per link).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+# the dry-run plans placements on the v5e production meshes
+PEAK_FLOPS = PEAKS["TPU v5 lite"]["flops"]
+HBM_BW = PEAKS["TPU v5 lite"]["hbm_bw"]
+ICI_BW = PEAKS["TPU v5 lite"]["ici_bw"]
+
+_PEAK_ENV = {"flops": "REPRO_PEAK_FLOPS", "hbm_bw": "REPRO_PEAK_HBM_BW",
+             "ici_bw": "REPRO_PEAK_ICI_BW"}
 
 
-def peaks() -> dict:
-    """The peak model every achieved-vs-peak gauge divides by: the TPU v5e
-    constants above, overridable per deployment via ``REPRO_PEAK_FLOPS`` /
-    ``REPRO_PEAK_HBM_BW`` / ``REPRO_PEAK_ICI_BW`` (so MFU on other
-    hardware is honest without a code change). Values are FLOP/s and B/s
-    per device."""
-    def _env(name, default):
+def peaks(device_kind: str | None = None) -> dict:
+    """The peaks every achieved-vs-peak gauge divides by, per device: the
+    ``PEAKS`` entry for ``device_kind`` (default: the first device's), with
+    any positive ``REPRO_PEAK_FLOPS`` / ``REPRO_PEAK_HBM_BW`` /
+    ``REPRO_PEAK_ICI_BW`` on top. A device missing from the table
+    contributes nothing: the result then holds only the overridden keys,
+    and callers emit no gauge for a peak they do not know."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    out = dict(PEAKS.get(device_kind, {}))
+    for key, name in _PEAK_ENV.items():
         try:
             v = float(os.environ.get(name, "") or 0)
         except ValueError:
             v = 0.0
-        return v if v > 0 else default
-    return {"flops": _env("REPRO_PEAK_FLOPS", PEAK_FLOPS),
-            "hbm_bw": _env("REPRO_PEAK_HBM_BW", HBM_BW),
-            "ici_bw": _env("REPRO_PEAK_ICI_BW", ICI_BW)}
+        if v > 0:
+            out[key] = v
+    return out
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -201,8 +217,6 @@ class Roofline:
 def analyze(compiled, *, model_flops_per_device: float = 0.0) -> dict:
     """Full analysis of one compiled executable."""
     ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):    # jax 0.4.x: list of per-program dicts
-        ca = ca[0] if ca else {}
     flops = float(ca.get("flops", 0.0))
     hbm = float(ca.get("bytes accessed", 0.0))
     txt = compiled.as_text()

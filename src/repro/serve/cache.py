@@ -4,7 +4,8 @@ Two pool layouts back the engine (DESIGN.md "Paged KV cache & prefix
 caching"):
 
 - **Paged (default).** Attention leaves hold ``num_pages`` fixed-size
-  physical pages — ``(layers, num_pages, page_size, ...)`` — shared by
+  physical pages — ``(layers, num_pages, KV, page_size, hd)`` for GQA,
+  ``(layers, num_pages, page_size, ...)`` for the MLA latent — shared by
   every slot through a per-slot *block table* (``(max_slots,
   pages_per_slot)`` int32 of physical page ids). Reads gather lanes (or
   fetch pages tile-wise inside ``flash_decode_paged``), writes scatter
@@ -50,8 +51,8 @@ def make_pool(model, max_slots: int, max_seq: int):
 
 
 def make_paged_pool(model, max_slots: int, page_size: int, num_pages: int):
-    """Paged pool: attention leaves are (layers, num_pages, page_size, ...)
-    physical pages; SSM leaves stay (layers, max_slots, ...) lanes."""
+    """Paged pool: attention leaves are (layers, num_pages, ...) physical
+    pages; SSM leaves stay (layers, max_slots, ...) lanes."""
     return model.init_paged_cache(max_slots, page_size, num_pages)
 
 

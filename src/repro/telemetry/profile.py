@@ -6,8 +6,8 @@ joins two sources:
 
 - **compile-time cost**: ``jitted.lower(*args).cost_analysis()`` — the
   per-device flops / HBM-bytes estimate XLA computes *without* building an
-  executable (verified cheap on jax 0.4.x: it reuses the jit trace cache
-  and never compiles). Collective bytes come from the caller's analytic
+  executable (cheap: it reuses the jit trace cache and never
+  compiles). Collective bytes come from the caller's analytic
   wire accounting (``exchanger.wire_summary`` / ``Engine.wire``) because
   the pre-optimization StableHLO text has no compiled-HLO collectives to
   parse — same modeling discipline as ``exchange/bytes_wire``.
@@ -17,7 +17,8 @@ joins two sources:
   that times the program (``train/step``, ``serve/decode_step``, ...).
 
 The join emits achieved-FLOPs / achieved-bandwidth / MFU gauges against
-:func:`repro.roofline.analysis.peaks` (env-overridable peak model), so
+:func:`repro.roofline.analysis.peaks` (keyed by ``device_kind``; a device
+with no known peak gets no ratio gauge), so
 "decode runs at 9% of the memory roofline" is a metric in every
 ``--metrics-out`` dump, not a bench-day observation.
 
@@ -80,20 +81,26 @@ class ProgramProfile:
         return self.coll_bytes / m if m > 0 else 0.0
 
     def roofline(self) -> dict:
-        """Ratios vs the (env-overridable) peak model; the roofline bound
-        time and which term dominates."""
+        """Ratios vs the device's peaks (``roofline.analysis.peaks``), and
+        the roofline bound time over the terms whose peak is known. Empty
+        on a device with no known peak."""
         from repro.roofline.analysis import peaks
         pk = peaks()
-        terms = {"compute": self.flops / pk["flops"],
-                 "memory": self.hbm_bytes / pk["hbm_bw"],
-                 "collective": self.coll_bytes / pk["ici_bw"]}
-        return {
-            "mfu": self.achieved_flops_s / pk["flops"],
-            "hbm_frac": self.achieved_hbm_bw / pk["hbm_bw"],
-            "coll_frac": self.achieved_coll_bw / pk["ici_bw"],
-            "t_roofline_s": max(terms.values()),
-            "bound": max(terms, key=terms.get),
-        }
+        terms, out = {}, {}
+        for term, key, amount, ratio, achieved in (
+                ("compute", "flops", self.flops, "mfu",
+                 self.achieved_flops_s),
+                ("memory", "hbm_bw", self.hbm_bytes, "hbm_frac",
+                 self.achieved_hbm_bw),
+                ("collective", "ici_bw", self.coll_bytes, "coll_frac",
+                 self.achieved_coll_bw)):
+            if key in pk:
+                terms[term] = amount / pk[key]
+                out[ratio] = achieved / pk[key]
+        if terms:
+            out["t_roofline_s"] = max(terms.values())
+            out["bound"] = max(terms, key=terms.get)
+        return out
 
     def gauges(self) -> dict:
         """The metric names/values this profile exports (flat
@@ -111,11 +118,13 @@ class ProgramProfile:
             rl = self.roofline()
             out[f"profile/{s}/achieved_flops_s"] = self.achieved_flops_s
             out[f"profile/{s}/achieved_hbm_bw"] = self.achieved_hbm_bw
-            out[f"profile/{s}/mfu"] = rl["mfu"]
-            out[f"profile/{s}/hbm_frac"] = rl["hbm_frac"]
+            for ratio in ("mfu", "hbm_frac"):
+                if ratio in rl:
+                    out[f"profile/{s}/{ratio}"] = rl[ratio]
             if self.coll_bytes:
                 out[f"profile/{s}/achieved_coll_bw"] = self.achieved_coll_bw
-                out[f"profile/{s}/coll_frac"] = rl["coll_frac"]
+                if "coll_frac" in rl:
+                    out[f"profile/{s}/coll_frac"] = rl["coll_frac"]
         return out
 
 
@@ -142,8 +151,8 @@ def capture(name: str, jfn, *args, coll_bytes: float = 0.0,
             **kwargs) -> ProgramProfile | None:
     """Record compile-time cost analysis for ``jfn`` called with ``args``.
 
-    Uses the AOT ``lower()`` path *without* ``compile()`` — on jax 0.4.x
-    the lowered cost analysis shares the jit trace cache (no retrace when
+    Uses the AOT ``lower()`` path *without* ``compile()`` — the lowered
+    cost analysis shares the jit trace cache (no retrace when
     the program already dispatched, and the trace is reused when it
     dispatches later) while an AOT ``compile()`` would pay a full second
     XLA compile. Never raises: failures count in
@@ -154,10 +163,7 @@ def capture(name: str, jfn, *args, coll_bytes: float = 0.0,
     t0 = time.perf_counter()
     try:
         lowered = jfn.lower(*args, **kwargs)
-        ca = lowered.cost_analysis()
-        if isinstance(ca, (list, tuple)):   # jax 0.4.x wraps in a list
-            ca = ca[0] if ca else {}
-        ca = ca or {}
+        ca = lowered.cost_analysis() or {}
         prof.flops = float(ca.get("flops", 0.0))
         prof.hbm_bytes = float(ca.get("bytes accessed", 0.0))
     except Exception as e:  # noqa: BLE001 — attribution must never break a run

@@ -31,7 +31,8 @@ Telemetry (host-side only — no op is added to the jitted step):
   step): ``train/loss``, ``train/lr``, ``train/examples_per_s``,
   ``train/model_flops_s`` (6·N·D achieved, cross-referenced from
   ``roofline.analysis.model_flops_6nd``), ``train/mfu`` when
-  ``REPRO_PEAK_FLOPS`` names the device peak, ``train/grad_norm`` when
+  ``roofline.analysis.peaks`` knows the device's FLOP/s peak,
+  ``train/grad_norm`` when
   the opt-in is on, and ``train/device_mem_bytes`` when the backend
   exposes ``memory_stats()``.
 
@@ -42,7 +43,6 @@ total-wall-clock meaning it always had.
 """
 from __future__ import annotations
 
-import os
 import time
 from dataclasses import dataclass, field
 
@@ -52,7 +52,7 @@ from repro import telemetry
 from repro.checkpoint.ckpt import restore_for_resume, save_checkpoint
 from repro.models.registry import Model
 from repro.optim.optimizers import Optimizer
-from repro.roofline.analysis import model_flops_6nd
+from repro.roofline.analysis import model_flops_6nd, peaks
 from repro.telemetry import anomaly, metrics, profile, trace
 from repro.train.engine import TrainPlan, build_engine
 
@@ -221,7 +221,7 @@ def train(model: Model, optimizer: Optimizer, lr_fn, mesh, batches, *,
                                              "sync_every")})
         metrics.gauge("exchange/bytes_per_step").set(wire["bytes_per_step"])
     n_params = _count_params(model)
-    peak_flops = float(os.environ.get("REPRO_PEAK_FLOPS", "0") or 0)
+    peak_flops = peaks().get("flops", 0.0)
     # step-time anomaly watch: spikes (robust-z vs a rolling median/MAD
     # window) and sustained regressions (fast-vs-slow EWMA) land as
     # anomaly/* counters + trace instants

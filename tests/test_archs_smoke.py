@@ -8,6 +8,7 @@ import pytest
 
 from repro.configs import (ASSIGNED_ARCHS, PAPER_ARCHS, get_smoke_config)
 from repro.core import get_exchanger, init_train_state, make_bsp_step
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import constant, sgd_momentum
 
@@ -52,7 +53,7 @@ def test_forward_and_loss(arch):
 def test_one_train_step(arch):
     cfg = get_smoke_config(arch)
     model = build_model(cfg)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     opt = sgd_momentum(weight_decay=0.0)
     state = init_train_state(model, opt, jax.random.key(0))

@@ -75,6 +75,31 @@ def test_capture_records_cost_and_join_emits_gauges(monkeypatch):
     assert reg["profile/test_prog/flops"].value == prof.flops
 
 
+def test_unknown_device_gets_no_peak_gauges(monkeypatch):
+    """A device missing from the peak table (the CPU here) yields no MFU
+    or roofline ratio unless a REPRO_PEAK_* names that peak."""
+    from repro.roofline.analysis import PEAKS, peaks
+
+    for name in ("REPRO_PEAK_FLOPS", "REPRO_PEAK_HBM_BW",
+                 "REPRO_PEAK_ICI_BW"):
+        monkeypatch.delenv(name, raising=False)
+    assert peaks("cpu") == {}
+    assert peaks("TPU v5 lite") == PEAKS["TPU v5 lite"]
+
+    @jax.jit
+    def f(x):
+        return x @ x
+
+    prof = profile.capture("test/nopeak", f, jnp.ones((32, 32)))
+    profile.observe("test/nopeak", 0.01)
+    gauges = prof.gauges()
+    assert gauges["profile/test_nopeak/achieved_flops_s"] > 0
+    assert not any(g.endswith(("/mfu", "/hbm_frac", "/coll_frac"))
+                   for g in gauges)
+    monkeypatch.setenv("REPRO_PEAK_HBM_BW", "1e9")
+    assert set(prof.roofline()) == {"hbm_frac", "t_roofline_s", "bound"}
+
+
 def test_capture_failure_is_a_counter_not_an_exception():
     class Broken:
         def lower(self, *a, **k):
@@ -292,7 +317,7 @@ def test_elastic_detects_injected_slowdown_within_three_rounds():
 # train/serve integration: gauges for train step, decode step, exchange half
 # ---------------------------------------------------------------------------
 
-def test_train_loop_emits_program_and_compile_gauges():
+def test_train_loop_emits_program_and_compile_gauges(monkeypatch):
     from repro.optim import constant, sgd_momentum
     from repro.train.loop import train
     from tests.test_engine import _batches, _mesh1, _tiny_lm
@@ -300,6 +325,8 @@ def test_train_loop_emits_program_and_compile_gauges():
     cfg, model = _tiny_lm()
     mesh = _mesh1()
     n = 4
+    # the CPU has no peak in the table: name one, or no MFU is emitted
+    monkeypatch.setenv("REPRO_PEAK_FLOPS", "1e12")
     train(model, sgd_momentum(), constant(0.01), mesh, _batches(cfg, n),
           num_steps=n, log_every=2, print_fn=lambda *a: None)
     profile.emit()
@@ -319,9 +346,10 @@ def test_train_loop_emits_program_and_compile_gauges():
     assert reg["compile/exchange_rs_s"].value > 0
 
 
-def test_serve_engine_emits_decode_attribution():
+def test_serve_engine_emits_decode_attribution(monkeypatch):
     from tests.test_telemetry import _serve_run
 
+    monkeypatch.setenv("REPRO_PEAK_FLOPS", "1e12")
     _, engine = _serve_run()
     profile.emit()
     reg = telemetry.default_registry()

@@ -20,6 +20,7 @@ from repro.dist.sharding import (MODEL_AXIS, batch_shardings, cache_shardings,
                                  dp_axes_of, dp_size_of, param_shardings,
                                  param_spec, sanitize_spec,
                                  set_replicate_attn, state_shardings)
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import (abstract_cache, abstract_state,
                                 train_batch_specs)
 from repro.models import build_model
@@ -176,7 +177,7 @@ def test_act_constrain_identity_outside_context():
 
 
 def test_act_constrain_inside_context_preserves_shape_and_values():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     jax.set_mesh(mesh)
     x = jnp.arange(2 * 8 * 16, dtype=jnp.float32).reshape(2, 8, 16)
     with act.activation_spec(P(None, None, "model")):
@@ -188,7 +189,7 @@ def test_act_constrain_inside_context_preserves_shape_and_values():
 
 
 def test_act_constrain_rank_pads():
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
     jax.set_mesh(mesh)
     with act.activation_spec(P(None, None, "model")):
         y2 = jax.jit(act.constrain)(jnp.ones((4, 16)))      # rank < spec
@@ -210,7 +211,7 @@ def test_act_contexts_nest():
 # ---------------------------------------------------------------------------
 
 def _real_mesh():
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return make_mesh((1, 1), ("data", "model"))
 
 
 def test_param_and_state_shardings_build():

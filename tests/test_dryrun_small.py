@@ -12,6 +12,7 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import PartitionSpec as P
 
+from repro.launch.mesh import make_mesh
 from repro.roofline.analysis import (Roofline, model_flops_6nd,
                                      parse_collectives)
 
@@ -52,7 +53,7 @@ def test_sanitize_spec_relocation():
     import numpy as np
     os.environ.setdefault("XLA_FLAGS", "")
     from repro.dist.sharding import sanitize_spec
-    mesh = jax.make_mesh((1,), ("model",))
+    mesh = make_mesh((1,), ("model",))
 
     class M:  # fake mesh with model=16 for divisibility logic
         axis_names = ("model",)
@@ -81,13 +82,14 @@ from repro.core.exchanger import get_exchanger
 from repro.core.gspmd import make_gspmd_step, fsdp_state_shardings
 from repro.dist.sharding import (batch_shardings, cache_shardings,
                                  state_shardings)
+from repro.launch.mesh import make_mesh
 from repro.launch.specs import (abstract_cache, abstract_state,
                                 decode_batch_specs, train_batch_specs, sds)
 from repro.models import build_model
 from repro.optim import sgd_momentum, constant
 from repro.roofline.analysis import analyze
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 jax.set_mesh(mesh)
 out = {}
 for arch in ["llama3.2-1b", "mamba2-1.3b", "deepseek-v2-lite-16b"]:

@@ -25,6 +25,7 @@ import pytest
 
 from repro.configs import get_smoke_config
 from repro.data.synthetic import LMTokenSource
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import adamw, constant, sgd_momentum
 from repro.train.engine import TrainPlan, build_engine
@@ -45,7 +46,7 @@ def _batches(cfg, n, bsz=8, seq=32):
 
 
 def _mesh1():
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     return mesh
 
@@ -337,6 +338,7 @@ import json
 import jax, numpy as np
 from repro.configs import get_smoke_config
 from repro.data.synthetic import LMTokenSource
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import constant, sgd_momentum
 from repro.train.engine import TrainPlan, build_engine
@@ -344,7 +346,7 @@ from repro.train.engine import TrainPlan, build_engine
 cfg = get_smoke_config("llama3.2-1b").with_overrides(
     vocab_size=64, d_ff=128, num_layers=2, dtype="float32")
 model = build_model(cfg)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 jax.set_mesh(mesh)
 src = LMTokenSource(cfg.vocab_size, 16, seed=0)
 batches = [src.batch(32, i) for i in range(4)]

@@ -11,6 +11,8 @@ import sys
 
 import pytest
 
+from repro.launch.mesh import make_mesh
+
 _SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
@@ -19,6 +21,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import PartitionSpec as P
 from repro.core.exchanger import EXCHANGERS, get_exchanger
 from repro.kernels import ops
+from repro.launch.mesh import make_mesh
 
 results = {}
 
@@ -72,8 +75,8 @@ def run_mesh(mesh, axes, tag):
                                               "tol": 1e-6,
                                               "ok": err <= 1e-6}
 
-run_mesh(jax.make_mesh((8,), ("data",)), ("data",), "dp8")
-run_mesh(jax.make_mesh((2, 4), ("pod", "data")), ("pod", "data"), "pod2x4")
+run_mesh(make_mesh((8,), ("data",)), ("data",), "dp8")
+run_mesh(make_mesh((2, 4), ("pod", "data")), ("pod", "data"), "pod2x4")
 print("RESULTS_JSON:" + json.dumps(results))
 """
 
@@ -123,7 +126,7 @@ def test_bucketed_exchange_single_device():
     from jax.sharding import PartitionSpec as P
     from repro.core.exchanger import get_exchanger
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     grads = {"a": jnp.arange(100.0), "b": jnp.ones((7, 3)),
              "c": jnp.full((2049,), 2.0)}

@@ -13,6 +13,7 @@ import pytest
 from repro.fault.inject import (FaultEvent, FaultPlan, bitflip,
                                 payload_checksum)
 from repro.fault.membership import MembershipController, WorkerState
+from repro.launch.mesh import make_mesh
 
 
 # ---------------------------------------------------------------------------
@@ -311,6 +312,7 @@ import jax
 
 from repro.configs import get_smoke_config
 from repro.data.synthetic import LMTokenSource
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import constant, sgd_momentum
 from repro.train.engine import TrainPlan, build_engine
@@ -335,8 +337,10 @@ out = {}
 plan_q = TrainPlan(algo="easgd", tau=2, alpha=0.5, exchanger="ar", quorum=4)
 sq, _ = elastic_train(model, opt, constant(0.05), batch_fn, plan=plan_q,
                       num_workers=4, num_steps=8, seed=0, print_fn=None)
-mesh = jax.make_mesh((4,), ("data",))
-jax.set_mesh(mesh)
+mesh = make_mesh((4,), ("data",))
+# the fixed-membership programs run under their mesh; the elastic runs
+# below build their own meshes as the fleet changes
+ctx = jax.set_mesh(mesh)
 eng = build_engine(TrainPlan(algo="easgd", tau=2, alpha=0.5, exchanger="ar"),
                    model, opt, constant(0.05), mesh)
 st = eng.init_state(jax.random.key(0))
@@ -358,6 +362,7 @@ absorb = np.asarray([0.5, 0.25, 0.0, 0.125], np.float32)  # staleness 0,1,-,3
 # lr=0 -> the sync step's local update is a no-op, params stay pre_stack
 state2, _ = progs.sync(state, batch_fn(1, 4), jax.random.fold_in(rng, 1),
                        absorb, absorb)
+ctx.__exit__(None, None, None)
 expect = [c + sum(absorb[i] * (s[i] - c) for i in range(4))
           for s, c in zip(pre_stack, pre_center)]
 out["absorb_math_err"] = maxerr(center_of(state2), expect)
@@ -468,7 +473,7 @@ def test_bsp_restart_after_corrupt_checkpoint(tmp_path):
     cfg = get_smoke_config("llama3.2-1b").with_overrides(
         vocab_size=64, d_ff=128, num_layers=2, dtype="float32")
     model = build_model(cfg)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     opt = sgd_momentum(weight_decay=0.0)
     src = LMTokenSource(cfg.vocab_size, 16, seed=0)

@@ -155,8 +155,9 @@ def test_default_interpret_cpu_and_env(monkeypatch):
     monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
     assert default_interpret() is True
     monkeypatch.delenv("REPRO_PALLAS_INTERPRET")
+    # the removed legacy switch no longer forces compiled mode off-TPU
     monkeypatch.setenv("REPRO_PALLAS_COMPILED", "1")
-    assert default_interpret() is False
+    assert default_interpret() is True
 
 
 @settings(max_examples=20, deadline=None)

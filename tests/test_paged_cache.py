@@ -326,16 +326,16 @@ def test_flash_decode_paged_matches_contiguous(window):
     key = jax.random.key(0)
     q = jax.random.normal(jax.random.fold_in(key, 1), (B, 1, H, Dk))
     k_pages = jax.random.normal(jax.random.fold_in(key, 2),
-                                (npg, ps, KV, Dk))
+                                (npg, KV, ps, Dk))
     v_pages = jax.random.normal(jax.random.fold_in(key, 3),
-                                (npg, ps, KV, Dv))
+                                (npg, KV, ps, Dv))
     tables = jnp.asarray([[1, 3, 5], [2, 4, 6]], jnp.int32)
     pos = jnp.asarray([13, 20], jnp.int32)
     got = flash_decode_paged(q, k_pages, v_pages, tables, pos,
                              page_size=ps, window=window, interpret=True)
     # oracle: gather each slot's lane contiguously, run the 1D kernel
-    lanes_k = k_pages[tables].reshape(B, -1, KV, Dk)
-    lanes_v = v_pages[tables].reshape(B, -1, KV, Dv)
+    lanes_k = k_pages[tables].swapaxes(2, 3).reshape(B, -1, KV, Dk)
+    lanes_v = v_pages[tables].swapaxes(2, 3).reshape(B, -1, KV, Dv)
     want = flash_decode(q, lanes_k, lanes_v, pos, window=window,
                         block_k=ps, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
@@ -361,7 +361,8 @@ def test_paged_pool_shardings_put_pages_on_data():
             jax.tree_util.tree_leaves_with_path(pool),
             jax.tree_util.tree_leaves_with_path(sh)):
         if cache_mod.is_paged_leaf(path):
-            assert leaf.shape[1] == 16 and leaf.shape[2] == 8
+            # (layers, pages, KV, page_size, hd): head-major pages
+            assert leaf.shape[1] == 16 and leaf.shape[3] == 8
             assert s.spec[1] == "data", f"page dim unsharded: {s.spec}"
         else:
             assert leaf.shape[1] == 3     # ssm lanes keep the slot dim

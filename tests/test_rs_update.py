@@ -25,6 +25,7 @@ import json
 import jax, jax.numpy as jnp, numpy as np
 from repro.core import (get_exchanger, init_sharded_train_state,
                         init_train_state, make_bsp_step)
+from repro.launch.mesh import make_mesh
 from repro.models.registry import Model
 from repro.optim import adamw, constant, sgd_momentum
 
@@ -44,7 +45,7 @@ def loss_fn(params, batch, rng=None, unroll=False):
     return loss, {"loss": loss, "aux": jnp.zeros(())}
 
 model = Model(cfg=None, init=init, loss_fn=loss_fn, forward=None)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 jax.set_mesh(mesh)
 batch = {"x": np.random.default_rng(0).normal(0, 1, (32, 33)).astype(
     np.float32)}

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_smoke_config
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.serve import Engine, Request, SamplingParams, SlotScheduler
 from repro.serve import cache as cache_mod
@@ -322,7 +323,7 @@ def test_scheduler_positions_track_cache_rows():
 def test_engine_on_mesh_matches_unsharded():
     cfg, model, params = _setup("llama3.2-1b")
     prompts, news = _mixed_workload(cfg, n_req=2)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     eng_m = Engine(model, params, max_slots=2, max_seq=48,
                    prefill_chunk=8, mesh=mesh)
     eng_u = Engine(model, params, max_slots=2, max_seq=48, prefill_chunk=8)
@@ -342,7 +343,7 @@ def test_checkpoint_roundtrip_into_serving(tmp_path):
     prompts, news = _mixed_workload(cfg, n_req=2)
     save_checkpoint(str(tmp_path / "ck"), params, step=7)
 
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     like = jax.device_put(params, param_shardings(mesh, params))
     restored = restore_checkpoint(str(tmp_path / "ck"), like)
     for a, b in zip(jax.tree.leaves(params), jax.tree.leaves(restored)):

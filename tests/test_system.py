@@ -1,5 +1,12 @@
 """End-to-end behaviour: multi-step training decreases loss (BSP subgd &
-awagd, EASGD), generation runs, GSPMD/ZeRO-1 path agrees with BSP."""
+awagd, EASGD), generation runs, GSPMD/ZeRO-1 path agrees with BSP, and the
+chip smoke run refuses to pass without a TPU."""
+import os
+import shutil
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,6 +16,7 @@ from repro.configs import get_smoke_config
 from repro.core import get_exchanger, init_train_state, make_bsp_step
 from repro.core.gspmd import make_gspmd_step
 from repro.data.synthetic import LMTokenSource
+from repro.launch.mesh import make_mesh
 from repro.models import build_model
 from repro.optim import constant, sgd_momentum
 from repro.train.engine import TrainPlan
@@ -29,7 +37,7 @@ def _batches(cfg, n, bsz=8, seq=32):
 
 def test_bsp_training_decreases_loss():
     cfg, model = _tiny_lm()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     opt = sgd_momentum(weight_decay=0.0)
     _, report = train(model, opt, constant(0.02), mesh,
@@ -42,7 +50,7 @@ def test_bsp_training_decreases_loss():
 
 def test_awagd_scheme_trains():
     cfg, model = _tiny_lm()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     opt = sgd_momentum(weight_decay=0.0)
     _, report = train(model, opt, constant(0.02), mesh,
@@ -53,7 +61,7 @@ def test_awagd_scheme_trains():
 
 def test_easgd_trains_center():
     cfg, model = _tiny_lm()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     opt = sgd_momentum(weight_decay=0.0)
     state, report = train(model, opt, constant(0.02), mesh,
@@ -69,7 +77,7 @@ def test_easgd_trains_center():
 
 def test_gspmd_zero1_matches_bsp_ar_one_step():
     cfg, model = _tiny_lm()
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     opt = sgd_momentum(weight_decay=0.0)
     state = init_train_state(model, opt, jax.random.key(0))
@@ -109,7 +117,7 @@ def test_microbatch_accumulation_matches_full_batch():
     from repro.models import build_model
     cfg = cfg.with_overrides(dtype="float32")
     model = build_model(cfg)
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = make_mesh((1,), ("data",))
     jax.set_mesh(mesh)
     opt = sgd_momentum(weight_decay=0.0)
     state = init_train_state(model, opt, jax.random.key(0))
@@ -126,3 +134,24 @@ def test_microbatch_accumulation_matches_full_batch():
         np.testing.assert_allclose(np.asarray(x, np.float32),
                                    np.asarray(y, np.float32),
                                    rtol=1e-4, atol=1e-6)
+
+
+@pytest.mark.parametrize("where", ["checkout", "alone"])
+def test_chip_smoke_fails_without_tpu(where, tmp_path):
+    """Under JAX_PLATFORMS=cpu, and from a directory holding only the
+    script, chip_smoke.py exits nonzero and prints no result line."""
+    script = Path(__file__).resolve().parents[1] / "chip_smoke.py"
+    if where == "alone":
+        script = Path(shutil.copy(script, tmp_path / "chip_smoke.py"))
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("PYTHONPATH", "REPRO_PALLAS_INTERPRET",
+                        "REPRO_ATTN_IMPL")}
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run([sys.executable, str(script)], cwd=script.parent,
+                          env=env, capture_output=True, text=True,
+                          timeout=300)
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout
+    # alone: no package to import, unless one is installed site-wide
+    reasons = ["no TPU"] + (["repro package"] if where == "alone" else [])
+    assert any(r in proc.stderr for r in reasons), proc.stderr[-2000:]

@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro import telemetry
+from repro.launch.mesh import make_mesh
 from repro.telemetry import metrics, trace
 from repro.telemetry.registry import (NOOP, Histogram, JsonlSink, MemorySink,
                                       Registry, exp_buckets)
@@ -367,7 +368,7 @@ def test_engine_exposes_wire_and_gspmd_does_not():
     assert eng.wire["bytes_per_step"] == 0
     assert len(eng.wire["per_bucket"]) == eng.wire["num_buckets"]
     if len(jax.devices()) >= 8:   # k>1 wire accounting needs a real 8-mesh
-        mesh8 = jax.make_mesh((8,), ("data",))
+        mesh8 = make_mesh((8,), ("data",))
         jax.set_mesh(mesh8)
         try:
             eng8 = build_engine(TrainPlan(algo="bsp", exchanger="asa16"),

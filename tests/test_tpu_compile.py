@@ -1,0 +1,149 @@
+"""Compile the main-path Pallas kernels for a described TPU v5e, at real
+widths, with no chip attached: the TPU compiler refuses here what it would
+refuse on the chip (tile-illegal block shapes, unsupported lowerings,
+VMEM overflow). Each test asserts the kernel survived as a
+``tpu_custom_call`` in the compiled HLO.
+
+The topology is described only inside the fixtures below — never while a
+module is imported — because one process at a time may load the TPU
+library; pytest-xdist workers that do not get this file never touch it.
+Widths: llama3.2-1b attention (H=32, KV=8, head_dim=64, S=2048, vocab
+128256) and a 4-worker gradient exchange (k=4) of a 4M-element bucket."""
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, SingleDeviceSharding
+
+from repro.kernels.chunk_sum import chunk_sum
+from repro.kernels.flash_attention import (flash_attention, flash_decode,
+                                           flash_decode_paged)
+from repro.kernels.fused_rs_update import fused_rs_update
+from repro.kernels.slot_gather import slot_gather_sample
+
+B, S, H, KV, D = 1, 2048, 32, 8, 64
+VOCAB = 128256
+K_WORKERS, BUCKET = 4, 4 * 2 ** 20
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "no TPU lib"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the
+    # persistent cache without one: keep these compiles out of it
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # a context mesh of CPU devices, left by an earlier test in this
+    # process, would clash with arguments placed on the described chip
+    ctx = jax.set_mesh(Mesh(np.asarray(desc.devices[:1]), ("chip",)))
+    yield desc
+    ctx.__exit__(None, None, None)
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile_text(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _qkv_shapes():
+    return [((B, S, H, D), jnp.bfloat16), ((B, S, KV, D), jnp.bfloat16),
+            ((B, S, KV, D), jnp.bfloat16)]
+
+
+def test_flash_attention_forward_compiles(one_chip):
+    txt = _compile_text(
+        lambda q, k, v: flash_attention(q, k, v, interpret=False),
+        one_chip, *_qkv_shapes())
+    assert "tpu_custom_call" in txt
+
+
+def test_flash_attention_forward_backward_compiles(one_chip):
+    def fwd_bwd(q, k, v):
+        loss = lambda q, k, v: flash_attention(
+            q, k, v, interpret=False).astype(jnp.float32).sum()
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    txt = _compile_text(fwd_bwd, one_chip, *_qkv_shapes())
+    # forward + dq + dkv kernels
+    assert txt.count("tpu_custom_call") >= 3
+
+
+def test_flash_attention_prefill_chunk_compiles(one_chip):
+    """The serve engine's prefill: a 32-row q chunk at per-slot offsets
+    against a 256-row cache lane."""
+    chunk, lane = 32, 256
+    txt = _compile_text(
+        lambda q, k, v, off: flash_attention(q, k, v, q_off=off,
+                                             interpret=False),
+        one_chip, ((B, chunk, H, D), jnp.bfloat16),
+        ((B, lane, KV, D), jnp.bfloat16), ((B, lane, KV, D), jnp.bfloat16),
+        ((B,), jnp.int32))
+    assert "tpu_custom_call" in txt
+
+
+def test_flash_decode_paged_compiles(one_chip):
+    slots, ps = 8, 16
+    pages_per_slot = S // ps
+    num_pages = slots * pages_per_slot + 1          # + the null page
+    txt = _compile_text(
+        lambda q, kp, vp, tbl, pos: flash_decode_paged(
+            q, kp, vp, tbl, pos, page_size=ps, interpret=False),
+        one_chip, ((slots, 1, H, D), jnp.bfloat16),
+        ((num_pages, KV, ps, D), jnp.bfloat16),
+        ((num_pages, KV, ps, D), jnp.bfloat16),
+        ((slots, pages_per_slot), jnp.int32), ((slots,), jnp.int32))
+    assert "tpu_custom_call" in txt
+
+
+def test_flash_decode_contiguous_compiles(one_chip):
+    slots = 8
+    txt = _compile_text(
+        lambda q, k, v, pos: flash_decode(q, k, v, pos, interpret=False),
+        one_chip, ((slots, 1, H, D), jnp.bfloat16),
+        ((slots, S, KV, D), jnp.bfloat16), ((slots, S, KV, D), jnp.bfloat16),
+        ((slots,), jnp.int32))
+    assert "tpu_custom_call" in txt
+
+
+def test_slot_gather_sample_compiles(one_chip):
+    slots = 8
+    txt = _compile_text(
+        lambda lg, oh, t, nz: slot_gather_sample(lg, oh, t, nz,
+                                                 interpret=False),
+        one_chip, ((slots, 1, VOCAB), jnp.float32), ((slots, 1), jnp.float32),
+        ((slots,), jnp.float32), ((slots, VOCAB), jnp.float32))
+    assert "tpu_custom_call" in txt
+
+
+def test_fused_rs_update_compiles(one_chip):
+    txt = _compile_text(
+        lambda recv, p, m, mask: fused_rs_update(recv, p, m, mask, 0.01,
+                                                 interpret=False),
+        one_chip, ((K_WORKERS, BUCKET), jnp.float32),
+        ((BUCKET,), jnp.float32), ((BUCKET,), jnp.float32),
+        ((BUCKET,), jnp.float32))
+    assert "tpu_custom_call" in txt
+
+
+def test_chunk_sum_compiles(one_chip):
+    txt = _compile_text(lambda x: chunk_sum(x, interpret=False), one_chip,
+                        ((K_WORKERS, BUCKET), jnp.float32))
+    assert "tpu_custom_call" in txt
