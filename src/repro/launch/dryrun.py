@@ -6,8 +6,8 @@ production meshes and record memory/cost/collective analysis.
 
 The XLA_FLAGS assignment below MUST run before jax initializes a backend
 (the host device count locks at first backend init, not at import — merely
-importing jax is safe; touching ``jax.devices()`` earlier is not). Do not import this module from processes
-that need 1 device.
+importing jax is safe; touching ``jax.devices()`` earlier is not). Do
+not import this module from processes that need 1 device.
 """
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"

@@ -369,10 +369,9 @@ def init_paged_decoder_cache(cfg: ArchConfig, max_slots: int,
     hold (count, num_pages, ...) physical pages (``attn_init_pages``)
     shared by every slot through a block table, SSM conv/state leaves
     keep one lane per slot (count, max_slots, ...) — they have no
-    sequence dim to page. The
-    meta/VLM row padding of ``init_decoder_cache`` does not apply: the
-    serve paths never write meta prefixes, and pages are allocated by
-    demand, not worst case."""
+    sequence dim to page. The meta/VLM row padding of
+    ``init_decoder_cache`` does not apply: the serve paths never write
+    meta prefixes, and pages are allocated by demand, not worst case."""
     dtype = dtype_of(cfg.dtype)
     caches = []
     for kind, count in segments(cfg):

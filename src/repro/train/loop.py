@@ -32,9 +32,8 @@ Telemetry (host-side only — no op is added to the jitted step):
   ``train/model_flops_s`` (6·N·D achieved, cross-referenced from
   ``roofline.analysis.model_flops_6nd``), ``train/mfu`` when
   ``roofline.analysis.peaks`` knows the device's FLOP/s peak,
-  ``train/grad_norm`` when
-  the opt-in is on, and ``train/device_mem_bytes`` when the backend
-  exposes ``memory_stats()``.
+  ``train/grad_norm`` when the opt-in is on, and
+  ``train/device_mem_bytes`` when the backend exposes ``memory_stats()``.
 
 The first step's wall time (compile + first execution) is recorded as
 ``TrainReport.compile_time`` and excluded from
