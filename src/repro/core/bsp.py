@@ -114,6 +114,21 @@ def _sharded_state_specs(optimizer: Optimizer, plan: RSPlan, ax: str):
             "step": P()}
 
 
+def _loss_and_grad(model: Model, params, batch, rng, unroll: bool):
+    """``value_and_grad`` of the model's loss, taken through ``jax.vjp`` so
+    that the forward ops run under the name scope ``forward`` and their
+    transposes under ``backward``: the device trace's ``tf_op`` metadata
+    then groups a step's ops by phase. Metadata only: the compiled program
+    is the one ``value_and_grad`` gives."""
+    with jax.named_scope("forward"):
+        loss, vjp_fn, metrics = jax.vjp(
+            lambda p: model.loss_fn(p, batch, rng, unroll=unroll), params,
+            has_aux=True)
+    with jax.named_scope("backward"):
+        (grads,) = vjp_fn(jnp.ones_like(loss))
+    return (loss, metrics), grads
+
+
 def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
                   lr_fn: Callable, mesh, data_axes=("data",),
                   scheme: str = "subgd", sum_fn=default_chunk_sum,
@@ -143,7 +158,10 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
     step metrics — the telemetry layer's single *in-graph* opt-in (it adds
     reductions to the compiled step, so it is off by default and gated by
     ``REPRO_TELEMETRY_GRADNORM``; non-sharded paths only, where the full
-    reduced gradient exists to be normed)."""
+    reduced gradient exists to be normed).
+
+    The step's ops run under the name scopes ``forward``, ``backward``,
+    ``exchange`` and ``update`` (metadata for the profiler's view)."""
     if overlap not in (None, "buckets"):
         raise ValueError(f"unknown overlap mode {overlap!r}")
     if overlap:
@@ -156,8 +174,7 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
 
     def grad_of(params, batch, rng):
         if microbatches <= 1:
-            return jax.value_and_grad(model.loss_fn, has_aux=True)(
-                params, batch, rng, unroll=unroll)
+            return _loss_and_grad(model, params, batch, rng, unroll)
 
         def split(v):
             return v.reshape(microbatches, v.shape[0] // microbatches,
@@ -166,9 +183,8 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
 
         def body(carry, mbatch):
             acc, loss_sum, aux_sum = carry
-            (loss, metrics), g = jax.value_and_grad(
-                model.loss_fn, has_aux=True)(params, mbatch, rng,
-                                             unroll=unroll)
+            (loss, metrics), g = _loss_and_grad(model, params, mbatch, rng,
+                                                unroll)
             acc = jax.tree.map(lambda a, b: a + b.astype(jnp.float32),
                                acc, g)
             return (acc, loss_sum + loss, aux_sum + metrics["aux"]), None
@@ -188,20 +204,25 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
             (loss, metrics), grads = grad_of(state["params"], batch, rng)
             lr = lr_fn(state["step"])
             if scheme == "subgd":
-                grads = exchanger.exchange(grads, axes, sum_fn=sum_fn,
-                                           bucket_bytes=bucket_bytes)
-                new_params, new_opt = optimizer.update(
-                    state["params"], grads, state["opt"], lr)
+                with jax.named_scope("exchange"):
+                    grads = exchanger.exchange(grads, axes, sum_fn=sum_fn,
+                                               bucket_bytes=bucket_bytes)
+                with jax.named_scope("update"):
+                    new_params, new_opt = optimizer.update(
+                        state["params"], grads, state["opt"], lr)
             elif scheme == "awagd":
-                new_params, new_opt = optimizer.update(
-                    state["params"], grads, state["opt"], lr)
+                with jax.named_scope("update"):
+                    new_params, new_opt = optimizer.update(
+                        state["params"], grads, state["opt"], lr)
                 # average weights AND momentum after the descent step
                 # ([7], [15]) — with the same bucketing as the gradients
-                new_params = exchanger.exchange(new_params, axes,
-                                                sum_fn=sum_fn,
-                                                bucket_bytes=bucket_bytes)
-                new_opt = exchanger.exchange(new_opt, axes, sum_fn=sum_fn,
-                                             bucket_bytes=bucket_bytes)
+                with jax.named_scope("exchange"):
+                    new_params = exchanger.exchange(
+                        new_params, axes, sum_fn=sum_fn,
+                        bucket_bytes=bucket_bytes)
+                    new_opt = exchanger.exchange(new_opt, axes,
+                                                 sum_fn=sum_fn,
+                                                 bucket_bytes=bucket_bytes)
             else:
                 raise ValueError(f"unknown scheme {scheme!r}")
             metrics = jax.tree.map(lambda v: jax.lax.pmean(v, axes), metrics)
@@ -255,6 +276,7 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
                 off += n
             return mask
 
+        @jax.named_scope("exchange")
         def rs_accum(grads):
             """RS one microbatch's grads to fp32 accumulables."""
             res, _ = exchanger.reduce_scatter(grads, axes, sum_fn=sum_fn,
@@ -283,8 +305,7 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
                 rest = jax.tree.map(lambda v: v[1:], mb)
 
                 def one_grad(mbatch):
-                    return jax.value_and_grad(model.loss_fn, has_aux=True)(
-                        params, mbatch, rng, unroll=unroll)
+                    return _loss_and_grad(model, params, mbatch, rng, unroll)
 
                 (l0, met0), g0 = one_grad(mb0)
                 acc0 = [jnp.zeros((plan.k, b.shard_len) if use_raw
@@ -323,8 +344,9 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
                     shards = [a / m for a in acc]
             else:
                 (loss, metrics), grads = grad_of(params, batch, rng)
-                res, _ = exchanger.reduce_scatter(grads, axes, sum_fn=sum_fn,
-                                                  plan=plan, raw=use_raw)
+                with jax.named_scope("exchange"):
+                    res, _ = exchanger.reduce_scatter(
+                        grads, axes, sum_fn=sum_fn, plan=plan, raw=use_raw)
                 fulls = res["full"]
                 if use_raw:
                     chunks = res["chunks"]
@@ -342,29 +364,32 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
                 # accumulate there, and only the compute copy goes through
                 # the (possibly lossy) wire-dtype all-gather
                 p_sh = state["opt"]["master"][bi]
-                mask_sh = shard_wd_mask(b, idx * b.shard_len)
                 st = state["opt"]["buckets"][bi]
-                if use_raw:
-                    p_new, st_new = optimizer.rs_fused_update(
-                        chunks[bi], p_sh, st, lr, mask_sh, scale,
-                        scales[bi])
-                else:
-                    p_new, st_new = optimizer.flat_update(
-                        p_sh, shards[bi], st, lr, mask_sh)
+                with jax.named_scope("update"):
+                    mask_sh = shard_wd_mask(b, idx * b.shard_len)
+                    if use_raw:
+                        p_new, st_new = optimizer.rs_fused_update(
+                            chunks[bi], p_sh, st, lr, mask_sh, scale,
+                            scales[bi])
+                    else:
+                        p_new, st_new = optimizer.flat_update(
+                            p_sh, shards[bi], st, lr, mask_sh)
                 new_bstates.append(st_new)
                 new_master.append(p_new)
                 # per-bucket dispatch: each AG depends only on its bucket's
                 # update, so gathers and updates interleave
-                new_flats.append(exchanger.all_gather(
-                    [p_new], plan, axes, wire_dtype=wire)[0])
+                with jax.named_scope("exchange"):
+                    new_flats.append(exchanger.all_gather(
+                        [p_new], plan, axes, wire_dtype=wire)[0])
             new_smalls, new_sstates = [], []
             for si, i in enumerate(plan.small):
                 p_fl = p_smalls[si].reshape(-1).astype(jnp.float32)
                 mask = (jnp.ones_like(p_fl) if len(plan.shapes[i]) > 1
                         else None)
-                p_new, st_new = optimizer.flat_update(
-                    p_fl, fulls[si].reshape(-1), state["opt"]["small"][si],
-                    lr, mask)
+                with jax.named_scope("update"):
+                    p_new, st_new = optimizer.flat_update(
+                        p_fl, fulls[si].reshape(-1),
+                        state["opt"]["small"][si], lr, mask)
                 new_smalls.append(p_new)
                 new_sstates.append(st_new)
             new_params = Exchanger.unpack(new_flats, new_smalls, plan)

@@ -538,48 +538,3 @@ def param_wire_dtype(exchanger: Exchanger):
     if exchanger.transfer_dtype == jnp.int8:
         return jnp.float16
     return exchanger.transfer_dtype
-
-
-def half_programs(exchanger: Exchanger, params_abs, mesh, *,
-                  axis: str = "data", bucket_bytes: int = 0):
-    """Standalone jitted RS / AG half programs over a ``(k, ...)`` gradient
-    stack — the per-program attribution path for the exchange halves.
-
-    The real halves run fused inside the train step, where no host code
-    can lower or time them separately; these programs rebuild each half in
-    isolation with the *same* plan, wire dtypes and collectives (the
-    ``bench_comm`` idiom), so their ``cost_analysis`` and micro-timed
-    durations attribute the step's exchange cost per half.
-
-    Returns ``(rs_fn, ag_fn, grads_abs, shards_abs, plan)``: jitted
-    callables plus abstract input stacks — lower them for cost capture,
-    or materialize zeros to micro-time an execution.
-    """
-    if exchanger.kind == "none":
-        raise ValueError("'none' exchanger has no halves to profile")
-    P = jax.sharding.PartitionSpec
-    k = int(mesh.shape[axis])
-    plan = exchanger.plan_for(params_abs, k, bucket_bytes)
-
-    def rs(gs):
-        per = jax.tree.map(lambda v: v[0], gs)
-        res, _ = exchanger.reduce_scatter(per, axis, plan=plan)
-        return ([s[None] for s in res["shards"]],
-                [f[None] for f in res["full"]])
-
-    def ag(sh):
-        flats = exchanger.all_gather([s[0] for s in sh], plan, axis,
-                                     wire_dtype=param_wire_dtype(exchanger))
-        return [f[None] for f in flats]
-
-    def _wrap(f):
-        return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P(axis),
-                                     out_specs=P(axis),
-                                     axis_names=frozenset({axis}),
-                                     check_vma=False))
-
-    grads_abs = jax.tree.map(
-        lambda l: jax.ShapeDtypeStruct((k, *l.shape), l.dtype), params_abs)
-    shards_abs = [jax.ShapeDtypeStruct((k, b.shard_len), jnp.float32)
-                  for b in plan.buckets]
-    return _wrap(rs), _wrap(ag), grads_abs, shards_abs, plan

@@ -14,6 +14,14 @@ containment per track); request-scoped lifecycles that overlap arbitrarily
 use the async pair :func:`async_begin`/:func:`async_end` keyed by an id
 (one Perfetto track per id).
 
+Each ``span`` and ``instant`` also enters a
+``jax.profiler.TraceAnnotation`` of the same name and attributes, so inside
+a ``jax.profiler`` session it lands in the ``.xplane.pb`` host plane, on
+the clock of the device events; outside a session the annotation records
+nothing. :func:`step` is a span that is also the profiler's step marker
+(``StepTraceAnnotation``). A span already open when a session starts, or
+still open when it stops, is missing from the profiler's trace.
+
 The event buffer is bounded (:data:`MAX_EVENTS`); overflow increments a
 drop counter rather than growing — a long-serving process can leave
 tracing on.
@@ -24,6 +32,8 @@ import json
 import os
 import threading
 import time
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 MAX_EVENTS = 1 << 18     # ~262k events; each is a small tuple
 
@@ -54,20 +64,24 @@ def _push(ev) -> None:
 
 
 class _Span:
-    """A live complete-event span (context manager)."""
-    __slots__ = ("name", "attrs", "t0")
+    """A live complete-event span (context manager), mirrored by its
+    profiler annotation ``note``."""
+    __slots__ = ("name", "attrs", "note", "t0")
 
-    def __init__(self, name: str, attrs):
+    def __init__(self, name: str, attrs, note):
         self.name = name
         self.attrs = attrs
+        self.note = note
 
     def __enter__(self):
+        self.note.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
         _push(("X", self.name, self.t0, t1 - self.t0, _tid(), self.attrs))
+        self.note.__exit__(*exc)
         return False
 
 
@@ -92,17 +106,27 @@ def _enabled() -> bool:
 
 def span(name: str, **attrs):
     """Context manager timing a host-side region. ``attrs`` land in the
-    exported event's ``args``."""
+    exported event's ``args`` and in the profiler event's stats."""
     if not _enabled():
         return _NOOP_SPAN
-    return _Span(name, attrs or None)
+    return _Span(name, attrs or None, TraceAnnotation(name, **attrs))
+
+
+def step(name: str, num: int):
+    """A span around one step of a loop, which the profiler also takes as
+    its step marker (``StepTraceAnnotation(name, step_num=num)``)."""
+    if not _enabled():
+        return _NOOP_SPAN
+    return _Span(name, {"step_num": num},
+                 StepTraceAnnotation(name, step_num=num))
 
 
 def instant(name: str, **attrs) -> None:
     """A zero-duration marker event."""
     if not _enabled():
         return
-    _push(("i", name, time.perf_counter(), 0.0, _tid(), attrs or None))
+    with TraceAnnotation(name, **attrs):
+        _push(("i", name, time.perf_counter(), 0.0, _tid(), attrs or None))
 
 
 def async_begin(name: str, aid, **attrs) -> None:

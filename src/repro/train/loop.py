@@ -18,20 +18,25 @@ is actually consumed so the skip stays metadata-only.
 
 Telemetry (host-side only — no op is added to the jitted step):
 
-- spans ``train/data`` / ``train/step`` / ``train/flush`` per step, so a
-  ``--trace-out`` Perfetto file shows where host wall time goes. Steps
-  dispatch asynchronously: ``train/step`` times *dispatch*; queued device
-  work surfaces in the ``train/flush`` span at log boundaries and in the
+- one ``train`` step span per iteration (``trace.step``, the profiler's
+  ``StepTraceAnnotation``) holding the phase spans ``train/data`` (the
+  ``next()`` on the batches), ``train/step`` (the host enqueue of the
+  step's programs, its key included), ``train/flush`` (the host blocked on
+  losses: the logged one, and the buffer moved to ``TrainReport.losses``),
+  and ``train/compile_block`` / ``train/checkpoint`` where they
+  happen; ``train/final_block`` follows the loop. Inside a
+  ``jax.profiler`` session these land in the trace's host plane on the
+  device events' clock; time in a ``train`` span outside its phase spans
+  is the loop's own bookkeeping. Steps dispatch asynchronously: queued
+  device work surfaces in ``train/flush`` at log boundaries and in the
   loop-iteration histogram.
 - histograms ``train/data_time_s`` / ``train/step_time_s`` (loop
-  iteration, first step excluded — that one is compile) and counters
-  ``train/steps`` / ``train/examples`` / ``train/tokens`` /
-  ``exchange/bytes_wire`` (the engine's analytic per-step wire traffic).
+  iteration, first step excluded — that one is compile) /
+  ``train/flush_time_s`` and counters ``train/steps`` /
+  ``train/examples`` / ``train/tokens`` / ``exchange/bytes_wire`` (the
+  engine's analytic per-step wire traffic).
 - gauges at flush boundaries only (one device sync per window, never per
   step): ``train/loss``, ``train/lr``, ``train/examples_per_s``,
-  ``train/model_flops_s`` (6·N·D achieved, cross-referenced from
-  ``roofline.analysis.model_flops_6nd``), ``train/mfu`` when
-  ``roofline.analysis.peaks`` knows the device's FLOP/s peak,
   ``train/grad_norm`` when the opt-in is on, and
   ``train/device_mem_bytes`` when the backend exposes ``memory_stats()``.
 
@@ -51,13 +56,8 @@ from repro import telemetry
 from repro.checkpoint.ckpt import restore_for_resume, save_checkpoint
 from repro.models.registry import Model
 from repro.optim.optimizers import Optimizer
-from repro.roofline.analysis import model_flops_6nd, peaks
-from repro.telemetry import anomaly, metrics, profile, trace
+from repro.telemetry import metrics, trace
 from repro.train.engine import TrainPlan, build_engine
-
-# exchange-half micro-timing materializes one (k, ...) zero-gradient stack;
-# skip it beyond this size (the cost capture via lower() still happens)
-_HALF_TIMING_CAP_BYTES = 256 << 20
 
 # when logging is off, losses still move to host in bounded windows (a long
 # run must not accumulate one device scalar per step)
@@ -74,13 +74,6 @@ class TrainReport:
     # that step excluded — the honest steady-state throughput
     compile_time: float = 0.0
     steady_examples_per_s: float = 0.0
-
-
-def _count_params(model: Model) -> int:
-    import numpy as np
-    abs_p = jax.eval_shape(model.init, jax.random.key(0))
-    return int(sum(int(np.prod(l.shape)) if l.shape else 1
-                   for l in jax.tree.leaves(abs_p)))
 
 
 def _batch_counts(batch) -> tuple[int, int]:
@@ -102,62 +95,6 @@ def _device_mem_bytes():
     if not stats:
         return None
     return stats.get("bytes_in_use")
-
-
-def _profile_exchange_halves(model: Model, plan: TrainPlan, mesh) -> None:
-    """Per-half exchange attribution: standalone jitted RS/AG programs
-    (``exchanger.half_programs``) are lowered for cost analysis and — when
-    the gradient stack is small enough — micro-timed on zeros so the
-    profile carries measured achieved-bandwidth for each half. Collective
-    bytes come from the analytic ``wire_summary`` (same numbers as
-    ``exchange/bytes_per_step``). Never raises into the train loop."""
-    import time as _time
-
-    import numpy as np
-
-    from repro.core.exchanger import (get_exchanger, half_programs,
-                                      wire_summary)
-    try:
-        ex = get_exchanger(plan.exchanger)
-        if ex.kind == "none":
-            return
-        axis = plan.data_axes[-1]
-        params_abs = jax.eval_shape(model.init, jax.random.key(0))
-        rs_fn, ag_fn, grads_abs, shards_abs, rsplan = half_programs(
-            ex, params_abs, mesh, axis=axis,
-            bucket_bytes=plan.bucket_bytes)
-        ws = wire_summary(ex, rsplan,
-                          param_ag=bool(plan.sharded_update or plan.overlap))
-        profile.capture("exchange/rs", rs_fn, grads_abs,
-                        coll_bytes=ws["rs_bytes"])
-        if shards_abs:
-            profile.capture("exchange/ag", ag_fn, shards_abs,
-                            coll_bytes=ws["ag_bytes"])
-        stack_bytes = sum(int(np.prod(l.shape)) * l.dtype.itemsize
-                          for l in jax.tree.leaves(grads_abs))
-        if stack_bytes > _HALF_TIMING_CAP_BYTES:
-            return
-        import jax.numpy as jnp
-        grads = jax.tree.map(lambda l: jnp.zeros(l.shape, l.dtype),
-                             grads_abs)
-        shards = [jnp.zeros(l.shape, l.dtype) for l in shards_abs]
-        for name, fn, args in (("exchange/rs", rs_fn, grads),
-                               ("exchange/ag", ag_fn, shards)):
-            if not args and name == "exchange/ag":
-                continue
-            t0 = _time.perf_counter()
-            out = fn(args)
-            jax.block_until_ready(out)
-            profile.compile_time(name, _time.perf_counter() - t0)
-            for _ in range(2):
-                t0 = _time.perf_counter()
-                out = fn(args)
-                jax.block_until_ready(out)
-                profile.observe(name, _time.perf_counter() - t0)
-    except Exception as e:  # noqa: BLE001 — attribution never breaks training
-        metrics.counter("profile/capture_errors").inc()
-        trace.instant("profile/exchange_halves_error",
-                      error=f"{type(e).__name__}: {e}")
 
 
 def train(model: Model, optimizer: Optimizer, lr_fn, mesh, batches, *,
@@ -208,7 +145,6 @@ def train(model: Model, optimizer: Optimizer, lr_fn, mesh, batches, *,
     g_loss = metrics.gauge("train/loss")
     g_lr = metrics.gauge("train/lr")
     g_exps = metrics.gauge("train/examples_per_s")
-    g_flops = metrics.gauge("train/model_flops_s")
     metrics.info("train/plan", algo=plan.algo, exchanger=plan.exchanger,
                  scheme=plan.scheme, arch=getattr(model.cfg, "name", ""))
     wire = engine.wire
@@ -219,18 +155,10 @@ def train(model: Model, optimizer: Optimizer, lr_fn, mesh, batches, *,
                                              "ag_dtype", "k", "num_buckets",
                                              "sync_every")})
         metrics.gauge("exchange/bytes_per_step").set(wire["bytes_per_step"])
-    n_params = _count_params(model)
-    peak_flops = peaks().get("flops", 0.0)
-    # step-time anomaly watch: spikes (robust-z vs a rolling median/MAD
-    # window) and sustained regressions (fast-vs-slow EWMA) land as
-    # anomaly/* counters + trace instants
-    det_step = anomaly.StreamDetector("train/step_time")
-    seen_progs: set = set()
 
     report = TrainReport()
     report.steps = start_step
     n_examples = 0
-    n_tokens = 0
     t0 = time.perf_counter()
     it = iter(batches)
     try:
@@ -246,96 +174,69 @@ def train(model: Model, optimizer: Optimizer, lr_fn, mesh, batches, *,
     device_grad_norm = None
     saved_at = None
     t_steady0 = t0
-    steady_base_ex = steady_base_tok = 0
+    steady_base_ex = 0
     for i in range(start_step, num_steps):
-        t_iter0 = time.perf_counter()
-        with trace.span("train/data"):
-            try:
-                batch = next(it)
-            except StopIteration:
-                break
-        t_step0 = time.perf_counter()
-        with trace.span("train/step", step=i):
-            state, step_metrics = engine.step(
-                state, batch, jax.random.fold_in(rng, i), step_idx=i)
-        device_losses.append(step_metrics["loss"])
-        device_grad_norm = step_metrics.get("grad_norm")
-        b_ex, b_tok = _batch_counts(batch)
-        n_examples += b_ex
-        n_tokens += b_tok
-        first_step = i == start_step
-        # which jitted program this iteration dispatched (the async loop
-        # alternates local/sync on the host-side step index)
-        if plan.is_async:
-            prog = ("train/sync" if (i + 1) % plan.tau == 0
-                    else "train/local")
-        else:
-            prog = "train/step"
-        if first_step:
-            # the first step carries compilation: block so its cost lands
-            # here (one extra sync for the whole run) and keep it out of
-            # the steady-state histograms/rates
-            with trace.span("train/compile_block"):
-                jax.block_until_ready(device_losses[-1])
-            report.compile_time = time.perf_counter() - t_step0
-            seen_progs.add(prog)
-            if profile.enabled() and wire:
-                with trace.span("profile/exchange_halves"):
-                    _profile_exchange_halves(model, plan, mesh)
-            t_steady0 = time.perf_counter()
-            steady_base_ex, steady_base_tok = n_examples, n_tokens
-        c_steps.inc()
-        c_examples.inc(b_ex)
-        c_tokens.inc(b_tok)
-        if wire:
-            c_wire.inc(wire["bytes_per_step"])
-        h_data.observe(t_step0 - t_iter0)
-        if not first_step:
-            t_now = time.perf_counter()
-            h_step.observe(t_now - t_iter0)
-            # join measured duration into the program's profile — under
-            # async dispatch the loop's backpressure amortizes device time
-            # into these iteration figures (same caveat as h_step). Each
-            # program's own first dispatch is its compiling call
-            # (train/sync first fires at step tau-1) — keep it out of the
-            # per-program mean like the first step stays out of h_step.
-            if prog in seen_progs:
-                profile.observe(prog, t_now - t_step0)
-            else:
-                seen_progs.add(prog)
-            det_step.observe(t_now - t_step0)
-        if log_every and (i % log_every == 0 or i == num_steps - 1):
-            with trace.span("train/flush", step=i):
-                t_f = time.perf_counter()
-                loss = float(device_losses[-1])       # device sync
-                h_flush.observe(time.perf_counter() - t_f)
-            print_fn(f"step {i:5d}  loss {loss:.4f}")
-            g_loss.set(loss)
-            g_lr.set(float(lr_fn(i)))
-            if device_grad_norm is not None:
-                metrics.gauge("train/grad_norm").set(
-                    float(device_grad_norm))
-            steady_t = time.perf_counter() - t_steady0
-            if steady_t > 0 and n_examples > steady_base_ex:
-                g_exps.set((n_examples - steady_base_ex) / steady_t)
-                flops_s = model_flops_6nd(
-                    n_params, n_tokens - steady_base_tok, "train") / steady_t
-                g_flops.set(flops_s)
-                if peak_flops > 0:
-                    metrics.gauge("train/mfu").set(flops_s / peak_flops)
-            mem = _device_mem_bytes()
-            if mem is not None:
-                metrics.gauge("train/device_mem_bytes").set(mem)
-            telemetry.flush(force=False)
-        if len(device_losses) >= flush_every:
-            report.losses.extend(float(l) for l in device_losses)
-            device_losses.clear()
-        if ckpt_path and ckpt_every and (i + 1) % ckpt_every == 0:
-            with trace.span("train/checkpoint", step=i + 1):
-                save_checkpoint(ckpt_path, state, step=i + 1,
-                                algo=plan.algo, keep=ckpt_keep)
-            saved_at = i + 1
-        report.steps = i + 1
+        with trace.step("train", i):
+            t_iter0 = time.perf_counter()
+            with trace.span("train/data"):
+                try:
+                    batch = next(it)
+                except StopIteration:
+                    break
+            t_step0 = time.perf_counter()
+            with trace.span("train/step", step=i):
+                state, step_metrics = engine.step(
+                    state, batch, jax.random.fold_in(rng, i), step_idx=i)
+            device_losses.append(step_metrics["loss"])
+            device_grad_norm = step_metrics.get("grad_norm")
+            b_ex, b_tok = _batch_counts(batch)
+            n_examples += b_ex
+            first_step = i == start_step
+            if first_step:
+                # the first step carries compilation: block so its cost
+                # lands here (one extra sync for the whole run) and keep it
+                # out of the steady-state histograms/rates
+                with trace.span("train/compile_block"):
+                    jax.block_until_ready(device_losses[-1])
+                report.compile_time = time.perf_counter() - t_step0
+                t_steady0 = time.perf_counter()
+                steady_base_ex = n_examples
+            c_steps.inc()
+            c_examples.inc(b_ex)
+            c_tokens.inc(b_tok)
+            if wire:
+                c_wire.inc(wire["bytes_per_step"])
+            h_data.observe(t_step0 - t_iter0)
+            if not first_step:
+                h_step.observe(time.perf_counter() - t_iter0)
+            if log_every and (i % log_every == 0 or i == num_steps - 1):
+                with trace.span("train/flush", step=i):
+                    t_f = time.perf_counter()
+                    loss = float(device_losses[-1])       # device sync
+                    h_flush.observe(time.perf_counter() - t_f)
+                print_fn(f"step {i:5d}  loss {loss:.4f}")
+                g_loss.set(loss)
+                g_lr.set(float(lr_fn(i)))
+                if device_grad_norm is not None:
+                    metrics.gauge("train/grad_norm").set(
+                        float(device_grad_norm))
+                steady_t = time.perf_counter() - t_steady0
+                if steady_t > 0 and n_examples > steady_base_ex:
+                    g_exps.set((n_examples - steady_base_ex) / steady_t)
+                mem = _device_mem_bytes()
+                if mem is not None:
+                    metrics.gauge("train/device_mem_bytes").set(mem)
+                telemetry.flush(force=False)
+            if len(device_losses) >= flush_every:
+                with trace.span("train/flush", step=i):
+                    report.losses.extend(float(l) for l in device_losses)
+                device_losses.clear()
+            if ckpt_path and ckpt_every and (i + 1) % ckpt_every == 0:
+                with trace.span("train/checkpoint", step=i + 1):
+                    save_checkpoint(ckpt_path, state, step=i + 1,
+                                    algo=plan.algo, keep=ckpt_keep)
+                saved_at = i + 1
+            report.steps = i + 1
     with trace.span("train/final_block"):
         jax.block_until_ready(state)
     report.wall_time = time.perf_counter() - t0

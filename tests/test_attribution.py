@@ -331,19 +331,20 @@ def test_train_loop_emits_program_and_compile_gauges(monkeypatch):
           num_steps=n, log_every=2, print_fn=lambda *a: None)
     profile.emit()
     reg = telemetry.default_registry()
-    # train step: cost captured, steady-state durations joined, MFU out
+    # train step: cost captured and the compiling call timed
     assert reg["profile/train_step/flops"].value > 0
     assert reg["profile/train_step/hbm_bytes"].value > 0
-    assert reg["profile/train_step/calls"].value == n - 1
-    assert reg["profile/train_step/mean_time_s"].value > 0
-    assert reg["profile/train_step/mfu"].value > 0
     assert reg["compile/train_step_s"].value > 0
-    # exchange halves: standalone jitted programs captured + micro-timed
-    assert profile.get("exchange/rs") is not None
-    assert profile.get("exchange/rs").captured
-    assert reg["profile/exchange_rs/hbm_bytes"].value > 0
-    assert reg["profile/exchange_rs/mfu"].value >= 0
-    assert reg["compile/exchange_rs_s"].value > 0
+    # host dispatch times are not joined into the step's profile (they are
+    # not the device's time under async dispatch), so no rate or MFU
+    names = set(reg.names())
+    for gone in ("calls", "mean_time_s", "mfu"):
+        assert f"profile/train_step/{gone}" not in names
+    # nor are the exchange halves rebuilt and timed as programs of their own
+    assert profile.get("exchange/rs") is None
+    assert profile.get("exchange/ag") is None
+    assert not any(g.startswith(("profile/exchange_", "compile/exchange_"))
+                   for g in names)
 
 
 def test_serve_engine_emits_decode_attribution(monkeypatch):

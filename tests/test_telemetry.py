@@ -418,12 +418,58 @@ def test_train_loop_records_metrics_and_compile_split(capsys):
     assert reg["exchange/bytes_wire"].value == 0
     assert reg["exchange/config"].labels["strategy"] == "asa"
     assert reg["train/examples_per_s"].value > 0
-    assert reg["train/model_flops_s"].value > 0
+    # the 6·params·tokens rate and its MFU are gone: for a convnet they
+    # counted images as tokens
+    assert "train/model_flops_s" not in reg.names()
+    assert "train/mfu" not in reg.names()
     assert reg["train/plan"].labels["algo"] == "bsp"
     # spans made it into the trace buffer (data/step per step + flushes)
     names = {e[1] for e in trace.events()}
     assert {"train/data", "train/step", "train/compile_block",
             "train/flush", "train/final_block"} <= names
+
+
+LOOP_SPANS = ("train", "train/data", "train/step", "train/flush")
+
+
+@pytest.mark.parametrize("on", [True, False])
+def test_train_loop_spans_reach_the_profiler_trace(tmp_path, on):
+    """Inside a ``jax.profiler`` session the loop's step and phase spans are
+    host events of the ``.xplane.pb``, by name; telemetry off records none."""
+    from jax.profiler import ProfileData
+
+    from repro.optim import constant, sgd_momentum
+    from repro.train.loop import train
+    from tests.test_engine import _batches, _mesh1, _tiny_lm
+
+    cfg, model = _tiny_lm()
+    mesh = _mesh1()
+    telemetry.set_enabled(on)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        train(model, sgd_momentum(), constant(0.01), mesh, _batches(cfg, 4),
+              num_steps=4, log_every=2, print_fn=lambda *a: None)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    host = [e for plane in ProfileData.from_file(str(path)).planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events]
+    count = {n: sum(e.name == n for e in host) for n in LOOP_SPANS}
+    if on:
+        # flushes: the logged losses of steps 0, 2 and 3, and the buffer
+        # of two losses moved to the report after steps 1 and 3
+        assert count == {"train": 4, "train/data": 4, "train/step": 4,
+                         "train/flush": 5}
+        steps = [e for e in host if e.name == "train"]
+        for e in host:
+            if e.name in LOOP_SPANS[1:]:
+                # each phase lies inside its step's span
+                assert any(s.start_ns <= e.start_ns
+                           and e.start_ns + e.duration_ns
+                           <= s.start_ns + s.duration_ns for s in steps)
+    else:
+        assert count == dict.fromkeys(LOOP_SPANS, 0)
 
 
 def test_train_loop_telemetry_off_identical_losses():
