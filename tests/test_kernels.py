@@ -1,5 +1,7 @@
 """Per-kernel validation: Pallas (interpret mode) vs pure-jnp oracle,
 sweeping shapes and dtypes."""
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -145,6 +147,69 @@ def test_fused_rs_update_int8_dequant():
                                atol=1e-7)
     np.testing.assert_allclose(np.asarray(mo), np.asarray(mr), rtol=1e-6,
                                atol=1e-7)
+
+
+def _rs_update_case(k, n, wire, seed):
+    key = jax.random.key(seed)
+    if wire == "int8":
+        recv = jax.random.randint(key, (k, n), -127, 128, dtype=jnp.int8)
+        scales = jax.random.uniform(jax.random.fold_in(key, 4), (k,)) * 0.01
+    else:
+        recv = (jax.random.normal(key, (k, n)) * 2).astype(wire)
+        scales = None
+    p = jax.random.normal(jax.random.fold_in(key, 1), (n,))
+    m = jax.random.normal(jax.random.fold_in(key, 2), (n,))
+    mask = (jax.random.uniform(jax.random.fold_in(key, 3), (n,))
+            > 0.5).astype(jnp.float32)
+    return recv, p, m, mask, scales
+
+
+# (k, n, block_rows): k = 1 is the one-chip cell's receive; block_rows
+# forces several blocks with a ragged last one (5000 pads to 40 rows of
+# 128 = 16 + 16 + 8; 12800 is 100 rows = 3 x 32 + 4); None derives it
+@pytest.mark.parametrize("k,n,block_rows", [(1, 5000, 16), (1, 12800, 32),
+                                            (1, 4096, None),
+                                            (4, 3001, 8), (3, 12800, None)])
+@pytest.mark.parametrize("wire", [jnp.float32, jnp.float16, "int8"])
+@pytest.mark.parametrize("nesterov", [False, True])
+def test_fused_rs_update_tiles_match_ref(k, n, block_rows, wire, nesterov):
+    """Lane-dense (rows, 128) tiling, a ragged last block, n % 128 != 0.
+
+    A one-chunk float receive (the one-chip cell's) has no sum order to
+    differ in, so the kernel must equal the compiled reference bit for
+    bit; elsewhere the CPU compiler may associate the chunk sum or fuse
+    the dequant multiply differently, so the tolerance of fp32 rounding."""
+    recv, p, m, mask, scales = _rs_update_case(k, n, wire, k * n + nesterov)
+    kw = dict(momentum=0.9, nesterov=nesterov, scale=1.0 / k,
+              weight_decay=5e-4, scales=scales)
+    po, mo = raw_rs_update(recv, p, m, mask, 0.05, block_rows=block_rows,
+                           interpret=True, **kw)
+    assert po.shape == mo.shape == (n,)
+    if k == 1 and wire != "int8":
+        pr, mr = jax.jit(functools.partial(ref.fused_rs_update_ref, **kw))(
+            recv, p, m, mask, 0.05)
+        np.testing.assert_array_equal(np.asarray(po), np.asarray(pr))
+        np.testing.assert_array_equal(np.asarray(mo), np.asarray(mr))
+    else:
+        pr, mr = ref.fused_rs_update_ref(recv, p, m, mask, 0.05, **kw)
+        np.testing.assert_allclose(np.asarray(po), np.asarray(pr), rtol=2e-5,
+                                   atol=1e-7)
+        np.testing.assert_allclose(np.asarray(mo), np.asarray(mr), rtol=2e-5,
+                                   atol=1e-7)
+
+
+@pytest.mark.parametrize("k,itemsize,want", [(1, 4, 1344), (4, 1, 1344),
+                                             (4, 2, 1152), (4, 4, 896),
+                                             (64, 4, 96), (512, 4, 32)])
+def test_fused_rs_update_tile_rows_fill_the_vmem_budget(k, itemsize, want):
+    """Rows per block: whole 32-row tiles whose double-buffered operands
+    stay inside the budget (at least one tile however large ``k``)."""
+    from repro.kernels.fused_rs_update import VMEM_TILE_BYTES, tile_rows
+    br = tile_rows(k, itemsize)
+    assert br == want and br % 32 == 0
+    row = 2 * 128 * (k * itemsize + 5 * 4)
+    assert br * row <= VMEM_TILE_BYTES or br == 32
+    assert (br + 32) * row > VMEM_TILE_BYTES
 
 
 def test_default_interpret_cpu_and_env(monkeypatch):

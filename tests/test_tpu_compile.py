@@ -8,8 +8,10 @@ The topology is described only inside the fixtures below — never while a
 module is imported — because one process at a time may load the TPU
 library; pytest-xdist workers that do not get this file never touch it.
 Widths: llama3.2-1b attention (H=32, KV=8, head_dim=64, S=2048, vocab
-128256) and a 4-worker gradient exchange (k=4) of a 4M-element bucket."""
+128256), a 4-worker gradient exchange (k=4) of a 4M-element bucket, and
+AlexNet's largest bucket (fc6's weights) as one chip updates it."""
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -26,6 +28,7 @@ from repro.kernels.slot_gather import slot_gather_sample
 B, S, H, KV, D = 1, 2048, 32, 8, 64
 VOCAB = 128256
 K_WORKERS, BUCKET = 4, 4 * 2 ** 20
+F6_W = 9216 * 4096                    # AlexNet fc6 weights: 37,748,736
 
 
 @pytest.fixture(scope="module")
@@ -147,3 +150,51 @@ def test_chunk_sum_compiles(one_chip):
     txt = _compile_text(lambda x: chunk_sum(x, interpret=False), one_chip,
                         ((K_WORKERS, BUCKET), jnp.float32))
     assert "tpu_custom_call" in txt
+
+
+def _operand_producers(txt, call):
+    """Opcode of the instruction behind each operand of the custom call
+    named ``call`` in compiled HLO text, looking through bitcasts (which
+    move no data)."""
+    insts = {}
+    for name, opcode, args in re.findall(
+            r"^\s*(?:ROOT )?%(\S+) = .*? ([\w-]+)\((.*?)\)", txt, re.M):
+        insts[name] = (opcode, re.findall(r"%([\w.-]+)", args))
+    calls = [n for n in insts if n.startswith(call + ".")
+             and insts[n][0] == "custom-call"]
+    assert len(calls) == 1, calls
+    out = []
+    for name in insts[calls[0]][1]:
+        while insts[name][0] == "bitcast":
+            name = insts[name][1][0]
+        out.append(insts[name][0])
+    return out
+
+
+def test_fused_rs_update_one_chip_f6_width_views_without_copies(one_chip):
+    """The one-chip cell's largest call: a k = 1 float32 receive over fc6's
+    weights. The lane-dense (rows, 128) view of every operand must be a
+    bitcast of its 1-D (or (1, n)) array, never a copy or transpose."""
+    txt = _compile_text(
+        lambda recv, p, m, mask: fused_rs_update(
+            recv, p, m, mask, 0.01, weight_decay=5e-4, interpret=False),
+        one_chip, ((1, F6_W), jnp.float32), ((F6_W,), jnp.float32),
+        ((F6_W,), jnp.float32), ((F6_W,), jnp.float32))
+    assert "tpu_custom_call" in txt
+    producers = _operand_producers(txt, "fused_rs_update")
+    # recv, p, m, mask, then lr
+    assert producers == ["parameter"] * 4 + ["constant"]
+
+
+def test_fused_rs_update_int8_compiles(one_chip):
+    """The asa8 wire: int8 chunks from four workers with one scale each."""
+    txt = _compile_text(
+        lambda recv, s, p, m, mask: fused_rs_update(
+            recv, p, m, mask, 0.01, scale=1.0 / K_WORKERS, scales=s,
+            interpret=False),
+        one_chip, ((K_WORKERS, BUCKET), jnp.int8), ((K_WORKERS,), jnp.float32),
+        ((BUCKET,), jnp.float32), ((BUCKET,), jnp.float32),
+        ((BUCKET,), jnp.float32))
+    assert "tpu_custom_call" in txt
+    assert not {"copy", "transpose"} & set(
+        _operand_producers(txt, "fused_rs_update")[2:])   # p, m, mask, lr
