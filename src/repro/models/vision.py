@@ -35,7 +35,13 @@ def _conv(p, x, stride=1, padding="SAME", groups=1):
     return y + p["b"]
 
 
-def _maxpool(x, k=3, s=2, padding="VALID"):
+def _maxpool(x, k=3, s=2, padding="VALID", ceil=False):
+    """``ceil``: Caffe's pooling, whose output size rounds up; the bottom and
+    right get -inf padding only where that adds a window, so a map on which
+    the two agree lowers to the plain VALID pool."""
+    if ceil:
+        padding = [(0, 0)] + [(0, -(-(n - k) // s) * s + k - n)
+                              for n in x.shape[1:3]] + [(0, 0)]
     return jax.lax.reduce_window(x, -jnp.inf, jax.lax.max,
                                  (1, k, k, 1), (1, s, s, 1), padding)
 
@@ -148,7 +154,12 @@ def vgg16_forward(p, x, train: bool = False, rng=None):
 
 
 # ---------------------------------------------------------------------------
-# GoogLeNet (Inception v1, with both aux classifiers)
+# GoogLeNet (Inception v1, with both aux classifiers; Szegedy et al. 2014,
+# Table 1 -> 13,378,280 params at 224 px and 1000 classes). What the paper
+# leaves out follows BVLC's bvlc_googlenet: max pools round their output size
+# up (maps 112, 56, 28, 14, 7 at 224 px), LRN over 5 channels with alpha 1e-4
+# spread over the window and k = 1, dropout 0.4 before the classifier and 0.7
+# in each auxiliary head, whose losses weigh 0.3.
 # ---------------------------------------------------------------------------
 
 # (1x1, 3x3red, 3x3, 5x5red, 5x5, pool_proj)
@@ -163,6 +174,13 @@ _INCEPTION = {
     "5a": (256, 160, 320, 32, 128, 128),
     "5b": (384, 192, 384, 48, 128, 128),
 }
+_POOL_AFTER = ("3b", "4e")
+_AUX_AFTER = ("4a", "4d")           # heads aux0, aux1
+_AUX_WEIGHT = 0.3
+_LRN = {"alpha": 1e-4 / 5, "k": 1.0}
+# (rate, N): a dropout draws its mask from fold_in(worker key, N), N being
+# the number of Caffe's loss the layer feeds (loss1, loss2: aux0, aux1)
+_DROPOUT = {"aux0": (0.7, 1), "aux1": (0.7, 2), "fc": (0.4, 3)}
 
 
 def _init_inception(key, cin, spec):
@@ -190,6 +208,31 @@ def _out_ch(spec):
     return spec[0] + spec[2] + spec[4] + spec[5]
 
 
+def _dropout(x, rng, name):
+    rate, fold = _DROPOUT[name]
+    keep = 1.0 - rate
+    mask = jax.random.bernoulli(jax.random.fold_in(rng, fold), keep, x.shape)
+    return x * mask / keep
+
+
+def _googlenet_trunk(p, x):
+    """The map after inception 5b, and the maps the auxiliary heads take."""
+    x = jax.nn.relu(_conv(p["c1"], x, stride=2))
+    x = _lrn(_maxpool(x, ceil=True), **_LRN)
+    x = jax.nn.relu(_conv(p["c2r"], x))
+    x = jax.nn.relu(_conv(p["c2"], x))
+    x = _maxpool(_lrn(x, **_LRN), ceil=True)
+    taps = []
+    for name in _INCEPTION:
+        with jax.named_scope(f"inception_{name}"):
+            x = _inception(p[f"i{name}"], x)
+        if name in _POOL_AFTER:
+            x = _maxpool(x, ceil=True)
+        if name in _AUX_AFTER:
+            taps.append(x)
+    return x, taps
+
+
 def init_googlenet(key, cfg: ArchConfig):
     C = cfg.num_classes
     p = {
@@ -202,46 +245,51 @@ def init_googlenet(key, cfg: ArchConfig):
         p[f"i{name}"] = _init_inception(jax.random.fold_in(key, 10 + i),
                                         cin, spec)
         cin = _out_ch(spec)
-    p["fc"] = _fc_init(jax.random.fold_in(key, 50), 1024, C)
-    # aux classifiers after 4a (512ch, 14x14 at 224px) and 4d (528ch)
-    aux_side = max(1, (cfg.image_size // 16 - 5) // 3 + 1)
-    for j, cin_aux in ((0, 512), (1, 528)):
+    p["fc"] = _fc_init(jax.random.fold_in(key, 50), cin, C)
+    # each head's width from the map the trunk gives it (512 channels at
+    # 4a, 528 at 4d; 14x14, pooled to 4x4, at 224 px)
+    side = cfg.image_size
+    _, taps = jax.eval_shape(lambda q: _googlenet_trunk(
+        q, jnp.zeros((1, side, side, 3), jnp.float32)), p)
+    for j, tap in enumerate(taps):
+        _, h, w, cin_aux = jax.eval_shape(lambda t: _avgpool(t, 5, 3),
+                                          tap).shape
+        if h * w == 0:
+            raise ValueError(
+                f"googlenet at {side} px: inception {_AUX_AFTER[j]}'s "
+                f"{tap.shape[1]}x{tap.shape[2]} map is smaller than the "
+                f"auxiliary head's 5x5 pool")
         p[f"aux{j}_conv"] = _conv_init(jax.random.fold_in(key, 60 + j),
                                        1, 1, cin_aux, 128)
         p[f"aux{j}_fc1"] = _fc_init(jax.random.fold_in(key, 62 + j),
-                                    128 * aux_side * aux_side, 1024)
+                                    128 * h * w, 1024)
         p[f"aux{j}_fc2"] = _fc_init(jax.random.fold_in(key, 64 + j), 1024, C)
     return p
 
 
-def _aux_head(p, j, x):
+def _aux_head(p, j, x, rng):
     x = _avgpool(x, 5, 3)
     x = jax.nn.relu(_conv(p[f"aux{j}_conv"], x))
     x = x.reshape(x.shape[0], -1)
     x = jax.nn.relu(x @ p[f"aux{j}_fc1"]["w"] + p[f"aux{j}_fc1"]["b"])
+    if rng is not None:
+        x = _dropout(x, rng, f"aux{j}")
     return x @ p[f"aux{j}_fc2"]["w"] + p[f"aux{j}_fc2"]["b"]
 
 
 def googlenet_forward(p, x, train: bool = False, rng=None):
-    """Returns (logits, [aux0_logits, aux1_logits])."""
-    x = jax.nn.relu(_conv(p["c1"], x, stride=2))
-    x = _maxpool(x)
-    x = _lrn(x)
-    x = jax.nn.relu(_conv(p["c2r"], x))
-    x = jax.nn.relu(_conv(p["c2"], x))
-    x = _lrn(x)
-    x = _maxpool(x)
+    """Returns (logits, [aux0_logits, aux1_logits]); the heads run only in
+    training, and ``rng`` (with ``train``) turns dropout on."""
+    rng = rng if train else None
+    x, taps = _googlenet_trunk(p, x)
     aux = []
-    for name, spec in _INCEPTION.items():
-        x = _inception(p[f"i{name}"], x)
-        if name in ("3b", "4e"):
-            x = _maxpool(x)
-        if train:
-            if name == "4a":
-                aux.append(_aux_head(p, 0, x))
-            elif name == "4d":
-                aux.append(_aux_head(p, 1, x))
+    if train:
+        for j, tap in enumerate(taps):
+            with jax.named_scope(f"aux{j}"):
+                aux.append(_aux_head(p, j, tap, rng))
     x = _gap(x)
+    if rng is not None:
+        x = _dropout(x, rng, "fc")
     logits = x @ p["fc"]["w"] + p["fc"]["b"]
     return logits, aux
 
@@ -258,18 +306,18 @@ def init_conv(key, cfg: ArchConfig):
 def conv_loss(params, batch, cfg: ArchConfig, rng=None, *, unroll=False):
     """batch: {images: (B,H,W,3), labels: (B,)}."""
     x, labels = batch["images"], batch["labels"]
+    aux = jnp.zeros((), jnp.float32)
     if cfg.conv_arch == "googlenet":
-        logits, aux = googlenet_forward(params, x, train=True, rng=rng)
-        loss = softmax_xent(logits, labels)
-        for a in aux:
-            loss = loss + 0.3 * softmax_xent(a, labels)
+        logits, aux_logits = googlenet_forward(params, x, train=True, rng=rng)
+        aux = _AUX_WEIGHT * sum(softmax_xent(a, labels) for a in aux_logits)
+        loss = softmax_xent(logits, labels) + aux
     elif cfg.conv_arch == "alexnet":
         logits = alexnet_forward(params, x, train=True, rng=rng)
         loss = softmax_xent(logits, labels)
     else:
         logits = vgg16_forward(params, x, train=True, rng=rng)
         loss = softmax_xent(logits, labels)
-    return loss, {"loss": loss, "aux": jnp.zeros((), jnp.float32)}
+    return loss, {"loss": loss, "aux": aux}
 
 
 def conv_predict(params, x, cfg: ArchConfig):
