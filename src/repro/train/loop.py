@@ -16,29 +16,45 @@ synthetic/index-keyed sources here, where producing batch i is O(1); a
 loader with expensive staging should defer device transfer until a batch
 is actually consumed so the skip stays metadata-only.
 
+Reads one step behind. Losses stay on the device between boundaries: a
+log step (every ``log_every``, and the last), or a full buffer of
+``_FLUSH_CAP`` losses. Each loss starts its copy to the host as it is
+enqueued, and a boundary's read waits until the next step is enqueued,
+so the device always has a step queued while the host blocks, and the
+next dispatch, its allocations and a late completion hide behind it.
+One blocking read per boundary prints the logged loss, sets the gauges
+and moves the buffer up to that step into ``TrainReport.losses``; the
+last boundary is read after ``train/final_block``. What is printed,
+gauged and reported is the same, in number, order and value, as with a
+synchronous read: only its time moves, by one step.
+
 Telemetry (host-side only — no op is added to the jitted step):
 
 - one ``train`` step span per iteration (``trace.step``, the profiler's
   ``StepTraceAnnotation``) holding the phase spans ``train/data`` (the
   ``next()`` on the batches), ``train/step`` (the host enqueue of the
   step's programs, its key included), ``train/flush`` (the host blocked on
-  losses: the logged one, and the buffer moved to ``TrainReport.losses``),
+  the previous boundary's losses, with the next step queued),
   and ``train/compile_block`` / ``train/checkpoint`` where they
   happen; ``train/final_block`` follows the loop. Inside a
   ``jax.profiler`` session these land in the trace's host plane on the
   device events' clock; time in a ``train`` span outside its phase spans
-  is the loop's own bookkeeping. Steps dispatch asynchronously: queued
-  device work surfaces in ``train/flush`` at log boundaries and in the
-  loop-iteration histogram.
+  is the loop's own bookkeeping.
 - histograms ``train/data_time_s`` / ``train/step_time_s`` (loop
   iteration, first step excluded — that one is compile) /
   ``train/flush_time_s`` and counters ``train/steps`` /
   ``train/examples`` / ``train/tokens`` / ``exchange/bytes_wire`` (the
   engine's analytic per-step wire traffic).
-- gauges at flush boundaries only (one device sync per window, never per
-  step): ``train/loss``, ``train/lr``, ``train/examples_per_s``,
-  ``train/grad_norm`` when the opt-in is on, and
-  ``train/device_mem_bytes`` when the backend exposes ``memory_stats()``.
+- counter ``train/queue_drains``: blocking reads the loop makes while no
+  later step is enqueued, ``train/compile_block`` and
+  ``train/final_block`` aside. It reads 0 in steady state; each in-loop
+  checkpoint save counts one (it reads the newest state), and so does
+  each ``train/lr`` read where JAX has no CPU backend to compute it on.
+- gauges at log boundaries only, read with the boundary's loss:
+  ``train/loss``, ``train/lr`` (the schedule computed on the host's CPU
+  device), ``train/examples_per_s``, ``train/grad_norm`` when the opt-in
+  is on, and ``train/device_mem_bytes`` when the backend exposes
+  ``memory_stats()``.
 
 The first step's wall time (compile + first execution) is recorded as
 ``TrainReport.compile_time`` and excluded from
@@ -47,8 +63,10 @@ total-wall-clock meaning it always had.
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
+from typing import NamedTuple
 
 import jax
 
@@ -74,6 +92,31 @@ class TrainReport:
     # that step excluded — the honest steady-state throughput
     compile_time: float = 0.0
     steady_examples_per_s: float = 0.0
+
+
+class _Boundary(NamedTuple):
+    """A step whose losses the loop reads once the next step is enqueued."""
+    step: int
+    log: bool               # print and set the gauges, besides the move
+    n_losses: int           # buffered losses up to and including ``step``
+    grad_norm: object       # the step's device grad norm, or None
+    n_examples: int         # examples enqueued up to and including ``step``
+
+
+def _host_lr(lr_fn, step: int, c_drains) -> float:
+    """``lr_fn(step)`` computed on the host's CPU device, so that the gauge
+    queues no transfer or program behind the accelerator's steps. Without
+    a CPU backend it runs on the default device and waits for every step
+    enqueued: a queue drain."""
+    try:
+        host = jax.local_devices(backend="cpu")[:1]
+    except RuntimeError:
+        host = []
+    if not host:
+        c_drains.inc()
+        return float(lr_fn(step))
+    with jax.set_mesh(jax.sharding.Mesh(host, ("host",))):
+        return float(lr_fn(step))
 
 
 def _batch_counts(batch) -> tuple[int, int]:
@@ -145,6 +188,7 @@ def train(model: Model, optimizer: Optimizer, lr_fn, mesh, batches, *,
     g_loss = metrics.gauge("train/loss")
     g_lr = metrics.gauge("train/lr")
     g_exps = metrics.gauge("train/examples_per_s")
+    c_drains = metrics.counter("train/queue_drains")
     metrics.info("train/plan", algo=plan.algo, exchanger=plan.exchanger,
                  scheme=plan.scheme, arch=getattr(model.cfg, "name", ""))
     wire = engine.wire
@@ -166,15 +210,39 @@ def train(model: Model, optimizer: Optimizer, lr_fn, mesh, batches, *,
             next(it)
     except StopIteration:
         return state, report
-    # losses stay on device between flush boundaries: a per-step float()
-    # would block dispatch every step. Flushed every log_every steps (or
-    # _FLUSH_CAP when logging is off) so the buffer stays bounded.
-    flush_every = min(log_every, _FLUSH_CAP) if log_every else _FLUSH_CAP
+    # losses stay on device between boundaries, each copied to the host
+    # as it is enqueued; a boundary's read waits until the next step is
+    # enqueued (see the module docstring)
     device_losses = []
-    device_grad_norm = None
+    pending = None
     saved_at = None
     t_steady0 = t0
     steady_base_ex = 0
+
+    def read(b: _Boundary, wait):
+        """Moves boundary ``b``'s losses to the report and logs it; ``wait``
+        spans the blocking read."""
+        with wait:
+            t_f = time.perf_counter()
+            losses = [float(l) for l in device_losses[:b.n_losses]]
+            h_flush.observe(time.perf_counter() - t_f)
+        del device_losses[:b.n_losses]
+        report.losses.extend(losses)
+        if not b.log:
+            return
+        print_fn(f"step {b.step:5d}  loss {losses[-1]:.4f}")
+        g_loss.set(losses[-1])
+        g_lr.set(_host_lr(lr_fn, b.step, c_drains))
+        if b.grad_norm is not None:
+            metrics.gauge("train/grad_norm").set(float(b.grad_norm))
+        steady_t = time.perf_counter() - t_steady0
+        if steady_t > 0 and b.n_examples > steady_base_ex:
+            g_exps.set((b.n_examples - steady_base_ex) / steady_t)
+        mem = _device_mem_bytes()
+        if mem is not None:
+            metrics.gauge("train/device_mem_bytes").set(mem)
+        telemetry.flush(force=False)
+
     for i in range(start_step, num_steps):
         with trace.step("train", i):
             t_iter0 = time.perf_counter()
@@ -187,8 +255,12 @@ def train(model: Model, optimizer: Optimizer, lr_fn, mesh, batches, *,
             with trace.span("train/step", step=i):
                 state, step_metrics = engine.step(
                     state, batch, jax.random.fold_in(rng, i), step_idx=i)
-            device_losses.append(step_metrics["loss"])
-            device_grad_norm = step_metrics.get("grad_norm")
+            loss = step_metrics["loss"]
+            loss.copy_to_host_async()
+            device_losses.append(loss)
+            grad_norm = step_metrics.get("grad_norm")
+            if grad_norm is not None:
+                grad_norm.copy_to_host_async()
             b_ex, b_tok = _batch_counts(batch)
             n_examples += b_ex
             first_step = i == start_step
@@ -197,7 +269,7 @@ def train(model: Model, optimizer: Optimizer, lr_fn, mesh, batches, *,
                 # lands here (one extra sync for the whole run) and keep it
                 # out of the steady-state histograms/rates
                 with trace.span("train/compile_block"):
-                    jax.block_until_ready(device_losses[-1])
+                    jax.block_until_ready(loss)
                 report.compile_time = time.perf_counter() - t_step0
                 t_steady0 = time.perf_counter()
                 steady_base_ex = n_examples
@@ -209,29 +281,17 @@ def train(model: Model, optimizer: Optimizer, lr_fn, mesh, batches, *,
             h_data.observe(t_step0 - t_iter0)
             if not first_step:
                 h_step.observe(time.perf_counter() - t_iter0)
-            if log_every and (i % log_every == 0 or i == num_steps - 1):
-                with trace.span("train/flush", step=i):
-                    t_f = time.perf_counter()
-                    loss = float(device_losses[-1])       # device sync
-                    h_flush.observe(time.perf_counter() - t_f)
-                print_fn(f"step {i:5d}  loss {loss:.4f}")
-                g_loss.set(loss)
-                g_lr.set(float(lr_fn(i)))
-                if device_grad_norm is not None:
-                    metrics.gauge("train/grad_norm").set(
-                        float(device_grad_norm))
-                steady_t = time.perf_counter() - t_steady0
-                if steady_t > 0 and n_examples > steady_base_ex:
-                    g_exps.set((n_examples - steady_base_ex) / steady_t)
-                mem = _device_mem_bytes()
-                if mem is not None:
-                    metrics.gauge("train/device_mem_bytes").set(mem)
-                telemetry.flush(force=False)
-            if len(device_losses) >= flush_every:
-                with trace.span("train/flush", step=i):
-                    report.losses.extend(float(l) for l in device_losses)
-                device_losses.clear()
+            if pending is not None:
+                # step i is queued behind the one read
+                read(pending, trace.span("train/flush", step=pending.step))
+                pending = None
+            log = bool(log_every) and (i % log_every == 0
+                                       or i == num_steps - 1)
+            if log or len(device_losses) >= _FLUSH_CAP:
+                pending = _Boundary(i, log, len(device_losses), grad_norm,
+                                    n_examples)
             if ckpt_path and ckpt_every and (i + 1) % ckpt_every == 0:
+                c_drains.inc()       # the save reads the newest state
                 with trace.span("train/checkpoint", step=i + 1):
                     save_checkpoint(ckpt_path, state, step=i + 1,
                                     algo=plan.algo, keep=ckpt_keep)
@@ -240,6 +300,8 @@ def train(model: Model, optimizer: Optimizer, lr_fn, mesh, batches, *,
     with trace.span("train/final_block"):
         jax.block_until_ready(state)
     report.wall_time = time.perf_counter() - t0
+    if pending is not None:      # the device is done: nothing to wait on
+        read(pending, contextlib.nullcontext())
     report.losses.extend(float(l) for l in device_losses)
     report.examples_per_s = n_examples / max(report.wall_time, 1e-9)
     steady_t = time.perf_counter() - t_steady0

@@ -457,10 +457,11 @@ def test_train_loop_spans_reach_the_profiler_trace(tmp_path, on):
             for line in plane.lines for e in line.events]
     count = {n: sum(e.name == n for e in host) for n in LOOP_SPANS}
     if on:
-        # flushes: the logged losses of steps 0, 2 and 3, and the buffer
-        # of two losses moved to the report after steps 1 and 3
+        # flushes: one read per boundary, each once the next step is
+        # enqueued: step 0's in iteration 1, step 2's in iteration 3; the
+        # last step's read follows train/final_block and waits on nothing
         assert count == {"train": 4, "train/data": 4, "train/step": 4,
-                         "train/flush": 5}
+                         "train/flush": 2}
         steps = [e for e in host if e.name == "train"]
         for e in host:
             if e.name in LOOP_SPANS[1:]:
