@@ -411,21 +411,3 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
         axis_names=frozenset(data_axes),
         check_vma=False)
     return step
-
-
-def make_loss_grad_step(model: Model, exchanger: Exchanger, mesh,
-                        data_axes=("data",), sum_fn=default_chunk_sum):
-    """Exchange-only step (gradient computation + exchange, no update) —
-    used by the communication benchmarks to isolate exchange cost."""
-    axes = norm_axes(data_axes)
-
-    def per_shard(params, batch, rng):
-        (_, _), grads = jax.value_and_grad(model.loss_fn, has_aux=True)(
-            params, batch, rng)
-        return exchanger.exchange(grads, axes, sum_fn=sum_fn)
-
-    return jax.shard_map(per_shard, mesh=mesh,
-                         in_specs=(P(), P(data_axes), P()),
-                         out_specs=P(),
-                         axis_names=frozenset(data_axes),
-                         check_vma=False)

@@ -38,7 +38,7 @@ but their mean tracks the BSP state by linearity) — the parity tested in
 The local (non-averaging) step is a *separate* function with no
 param-sized collective at all — the engine dispatches sync vs local by
 ``step_idx % tau``, so at tau > 1 the wire really is idle between
-averaging rounds (measured in ``benchmarks/bench_easgd.py``).
+averaging rounds.
 """
 from __future__ import annotations
 

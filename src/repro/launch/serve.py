@@ -5,8 +5,7 @@
         --temperature 0.8 --top-k 40 --top-p 0.95
 
 ``--reference`` runs the old static-batch greedy path
-(``train.serve.generate``) instead — the parity oracle and the baseline
-``bench_serve`` measures the engine against.
+(``train.serve.generate``) instead — the engine's parity oracle.
 
 SLO guardrails (DESIGN.md "Serve robustness"): ``--deadline-ms`` stamps a
 per-request budget (hopeless requests are shed, in-flight ones past

@@ -272,7 +272,7 @@ def gqa_attend_blockwise(q, k, v, q_pos, k_pos, window, a: AttentionConfig,
     """Flash-style attention: lax.scan over KV blocks with an online
     softmax, so the (Sq, Sk) score matrix is never materialized in HBM —
     the per-step working set is (Sq, block). Beyond-paper optimization for
-    the memory-bound prefill/train shapes (see EXPERIMENTS.md §Perf).
+    the memory-bound prefill/train shapes.
 
     ``v`` may have a different trailing dim than q/k (the MLA absorbed
     layout: q/k in the latent+rope space, v = the latent); ``scale``

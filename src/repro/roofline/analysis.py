@@ -255,7 +255,7 @@ def attention_flops_bytes(*, batch: int, q_len: int, kv_len: int,
     ``pairs`` counts surviving (q, k) interactions: query at absolute
     position ``q_start + i`` sees ``min(pos+1, kv_len)`` keys, clipped to
     ``window`` when one is set — so windowed layers get a *linear* (not
-    quadratic) compute term and the bench can report achieved-vs-roofline
+    quadratic) compute term and a caller can report achieved-vs-roofline
     per masking mode. FLOPs: 2·(Dk+Dv) per pair per head forward (QK^T +
     PV); the backward recomputes the score tile and runs the dQ/dK/dV
     matmuls (3·Dk + 2·Dv dots of 2 FLOPs each). Bytes: one q/k/v read +

@@ -15,7 +15,7 @@ caching"):
   and keep one lane per slot: ``(layers, max_slots, ...)``.
 - **Contiguous (legacy / oracle).** ``model.init_cache(max_slots,
   max_seq)``: one private ``max_seq`` lane per slot. Kept as the parity
-  oracle for the paged engine and for A/B density benchmarks.
+  oracle for the paged engine and as the baseline of its density test.
 
 Physical page 0 is the **null page**: block tables initialize (and reset)
 to 0, idle slots and pad-row scatters land there harmlessly, and it is
@@ -238,7 +238,7 @@ class PageAllocator:
         self._lru: OrderedDict[bytes, None] = OrderedDict()
         # pages withheld from circulation by fault injection (pagepress)
         self.held: list[int] = []
-        # counters (pages unless noted; read by EngineStats / bench)
+        # counters (pages unless noted; read by EngineStats)
         self.hits = 0
         self.lookups = 0
         self.hit_tokens = 0

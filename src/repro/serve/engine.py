@@ -340,7 +340,8 @@ class Engine:
         share a physical page pool through block tables, sized by
         ``num_pages`` (0 = worst-case auto: every slot can still reach
         ``max_seq``). ``page_size=0`` keeps the contiguous per-slot pool —
-        the parity oracle and the A/B baseline for density benchmarks.
+        the parity oracle, and the baseline of the paged pool's density
+        test (``tests/test_paged_cache.py``).
         ``prefix_cache`` hands shared page-aligned prompt prefixes to new
         requests by refcount (attention families only; SSM state is not
         reconstructible from cache pages, so it is ignored there).

@@ -9,13 +9,13 @@ Three pieces (see DESIGN.md "Telemetry"):
 - a low-overhead **span API** (``with trace.span("exchange/rs",
   bytes=n):``) exporting Chrome-trace/Perfetto JSON;
 - one **schema** (versioned, with host/device/backend run context) shared
-  by live runs and ``BENCH_*.json`` artifacts.
+  by every metrics and trace file a run writes.
 
 Host-side only: instrumentation never adds an op to a jitted program
 (grad-norm is the single, explicit opt-in exception). Default-on;
 ``REPRO_TELEMETRY=0`` switches every accessor to a shared no-op whose
-cost is one flag test (pinned <1% step time by
-``benchmarks/bench_telemetry.py``).
+cost is one flag test (its allocation-free path is tested in
+``tests/test_telemetry.py``).
 """
 from repro.telemetry import metrics, trace
 from repro.telemetry import anomaly, profile
@@ -29,7 +29,6 @@ from repro.telemetry.registry import (ConsoleSink, Counter, Gauge,
                                       MemorySink, NOOP, Registry,
                                       TIME_BUCKETS, exp_buckets)
 from repro.telemetry.schema import (SCHEMA_VERSION, run_context, run_record,
-                                    validate_bench_json, validate_bench_obj,
                                     validate_metrics_jsonl, validate_record,
                                     validate_trace)
 
@@ -40,7 +39,6 @@ __all__ = [
     "flush", "reset", "set_enabled",
     "ConsoleSink", "Counter", "Gauge", "Histogram", "Info", "JsonlSink",
     "MemorySink", "NOOP", "Registry", "TIME_BUCKETS", "exp_buckets",
-    "SCHEMA_VERSION", "run_context", "run_record", "validate_bench_json",
-    "validate_bench_obj", "validate_metrics_jsonl", "validate_record",
-    "validate_trace",
+    "SCHEMA_VERSION", "run_context", "run_record", "validate_metrics_jsonl",
+    "validate_record", "validate_trace",
 ]

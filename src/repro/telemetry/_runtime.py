@@ -4,8 +4,8 @@ attached registries, and sinks.
 Telemetry is **default-on** (recording into in-process state costs ~a
 microsecond per call); ``REPRO_TELEMETRY=0`` in the environment flips the
 whole surface to the shared no-op fast path before anything records.
-``set_enabled`` flips it at runtime (the overhead benchmark and the
-on-vs-off parity tests use this).
+``set_enabled`` flips it at runtime (the on-vs-off parity tests use
+this).
 
 The switch gates *recording through the module-level accessors* — a
 standalone :class:`~repro.telemetry.registry.Registry` instance keeps

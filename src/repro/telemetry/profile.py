@@ -231,20 +231,3 @@ def emit(registry=None) -> None:
     for prof in _profiles.values():
         for gname, v in prof.gauges().items():
             registry.gauge(gname).set(v)
-
-
-def summary() -> list:
-    """One dict per captured program — the report CLI's table source."""
-    out = []
-    for name in sorted(_profiles):
-        p = _profiles[name]
-        row = {"program": name, "flops": p.flops, "hbm_bytes": p.hbm_bytes,
-               "coll_bytes": p.coll_bytes, "calls": p.calls,
-               "mean_time_s": p.mean_time_s,
-               "compile_time_s": p.compile_time_s,
-               "achieved_flops_s": p.achieved_flops_s,
-               "achieved_hbm_bw": p.achieved_hbm_bw}
-        if p.captured and p.calls:
-            row.update(p.roofline())
-        out.append(row)
-    return out

@@ -1,15 +1,14 @@
 """The telemetry wire schema: versioning, run context, validators.
 
-One schema is shared by three producers so they stay comparable:
+One schema is shared by its producers so they stay comparable:
 
 - live train/serve runs (``--metrics-out`` JSONL / ``--trace-out`` trace)
-- ``benchmarks/run.py --json`` (``BENCH_*.json`` artifacts)
 - tests (in-memory snapshots)
 
 Every metrics record carries ``schema_version`` + ``ts``; every file opens
 with a ``run`` record describing the host/device/backend that produced it
-(the attribution satellite: a BENCH json or a metrics JSONL from three PRs
-ago says *what machine and backend* its numbers came from).
+(a metrics JSONL from an older commit still says *what machine and
+backend* its numbers came from).
 
 The validators are dependency-free (no jsonschema) and are what the CI
 telemetry-smoke step runs against freshly produced files.
@@ -195,36 +194,3 @@ def validate_trace(path: str) -> list:
                 else:
                     open_async[key] -= 1
     return errs
-
-
-def validate_bench_obj(obj, where: str = "bench") -> list:
-    """Validate an in-memory BENCH object (what ``benchmarks/run.py``
-    checks *before* writing ``--json``)."""
-    errs: list = []
-    if not isinstance(obj, dict):
-        return [f"{where}: not an object: {type(obj).__name__}"]
-    if obj.get("schema_version") != SCHEMA_VERSION:
-        _err(errs, where, "missing/stale schema_version")
-    if "backend" not in obj.get("run", {}):
-        _err(errs, where, "missing run context")
-    rows = obj.get("rows")
-    if not isinstance(rows, list):
-        _err(errs, where, "missing rows list")
-    else:
-        for i, r in enumerate(rows):
-            if not isinstance(r, dict) or "name" not in r:
-                _err(errs, f"{where}:rows[{i}]", "row without name")
-            elif not isinstance(r.get("us_per_call"), (int, float)):
-                _err(errs, f"{where}:rows[{i}]",
-                     "row without numeric us_per_call")
-    return errs
-
-
-def validate_bench_json(path: str) -> list:
-    """Validate a ``BENCH_*.json`` artifact written by benchmarks/run.py."""
-    with open(path) as f:
-        try:
-            obj = json.load(f)
-        except json.JSONDecodeError as e:
-            return [f"{path}: bad json: {e}"]
-    return validate_bench_obj(obj, path)

@@ -1,10 +1,8 @@
-"""Per-program attribution, anomaly detection, and the bench-regression
-gate: ProgramProfile cost capture + gauge math, StreamDetector /
-FleetDetector firing rules, detection-driven straggler marking through
-``elastic_train`` (multi-device subprocess), the ``benchmarks/history``
-comparator tolerance bands, ``run.py --check`` wiring, and the
-adversarial-input contracts of the validators (diagnostics, never
-tracebacks)."""
+"""Per-program attribution and anomaly detection: ProgramProfile cost
+capture + gauge math, StreamDetector / FleetDetector firing rules,
+detection-driven straggler marking through ``elastic_train``
+(multi-device subprocess), and the adversarial-input contracts of the
+validators (diagnostics, never tracebacks)."""
 import json
 import os
 import subprocess
@@ -16,13 +14,9 @@ import numpy as np
 import pytest
 
 from repro import telemetry
-from repro.telemetry import anomaly, metrics, profile, trace
-from repro.telemetry.schema import (SCHEMA_VERSION, validate_bench_obj,
-                                    validate_metrics_jsonl, validate_record,
-                                    validate_trace)
-
-_ROOT = os.path.join(os.path.dirname(__file__), "..")
-sys.path.insert(0, os.path.abspath(_ROOT))     # for `import benchmarks.*`
+from repro.telemetry import anomaly, profile, trace
+from repro.telemetry.schema import (SCHEMA_VERSION, validate_metrics_jsonl,
+                                    validate_record, validate_trace)
 
 
 @pytest.fixture(autouse=True)
@@ -366,117 +360,6 @@ def test_serve_engine_emits_decode_attribution(monkeypatch):
 
 
 # ---------------------------------------------------------------------------
-# history comparator + run.py --check
-# ---------------------------------------------------------------------------
-
-def _bench_obj(rows, quick=True):
-    return {"schema_version": SCHEMA_VERSION,
-            "run": {"host": "h", "backend": "cpu"},
-            "quick": quick, "rows": rows}
-
-
-def test_history_direction_heuristics():
-    from benchmarks.history import direction
-    assert direction("tok_s") == 1
-    assert direction("decode_tok_s") == 1
-    assert direction("speedup") == 1
-    assert direction("continuous_over_static") == 1
-    assert direction("achieved_bw") == 1
-    assert direction("us_per_call") == -1
-    assert direction("p50_ms") == -1
-    assert direction("bwd_ms") == -1          # "bw" token must NOT match
-    assert direction("compiles") == -1
-    assert direction("workspace_bytes") == -1
-    assert direction("exposed_ms") == -1
-    assert direction("weird_quantity") == 0
-
-
-def test_history_twenty_percent_tok_s_regression_fails():
-    from benchmarks.history import compare, REGRESSED
-    base = _bench_obj([{"name": "serve/engine", "us_per_call": 100.0,
-                        "derived": "tok_s=100.0;p50_ms=1.0"}])
-    bad = _bench_obj([{"name": "serve/engine", "us_per_call": 100.0,
-                       "derived": "tok_s=80.0;p50_ms=1.0"}])
-    verdicts = compare(base, bad, default_rtol=0.15)
-    reg = {v.metric: v for v in verdicts if v.status == REGRESSED}
-    assert "serve/engine.tok_s" in reg
-    # the baseline against itself passes clean
-    assert all(v.status != REGRESSED
-               for v in compare(base, base, default_rtol=0.15))
-
-
-def test_history_lower_better_and_tolerance_resolution():
-    from benchmarks.history import compare, REGRESSED, OK
-    base = _bench_obj([{"name": "x", "us_per_call": 100.0,
-                        "derived": "compiles=1"}])
-    slow = _bench_obj([{"name": "x", "us_per_call": 200.0,
-                        "derived": "compiles=2"}])
-    v = {x.metric: x for x in compare(base, slow, default_rtol=0.15)}
-    assert v["x.us_per_call"].status == REGRESSED
-    assert v["x.compiles"].status == REGRESSED
-    # bare-key tolerance entry loosens one metric, not the other
-    v = {x.metric: x for x in compare(
-        base, slow, default_rtol=0.15,
-        per_metric={"us_per_call": 2.0})}
-    assert v["x.us_per_call"].status == OK
-    assert v["x.compiles"].status == REGRESSED
-
-
-def test_history_missing_and_new_metrics_do_not_gate():
-    from benchmarks.history import compare, MISSING, NEW, REGRESSED
-    base = _bench_obj([{"name": "a", "us_per_call": 1.0, "derived": ""}])
-    new = _bench_obj([{"name": "b", "us_per_call": 1.0, "derived": ""}])
-    verdicts = compare(base, new)
-    statuses = {v.metric: v.status for v in verdicts}
-    assert statuses["a.us_per_call"] == MISSING
-    assert statuses["b.us_per_call"] == NEW
-    assert not any(v.status == REGRESSED for v in verdicts)
-
-
-def test_history_error_rows_dropped_and_cli_gate(tmp_path):
-    from benchmarks.history import main, metrics_of
-    base = _bench_obj([{"name": "a", "us_per_call": 10.0,
-                        "derived": "tok_s=50"},
-                       {"name": "comm/ERROR", "us_per_call": 0,
-                        "derived": "RuntimeError:boom"}])
-    assert "comm/ERROR.us_per_call" not in metrics_of(base)
-    bad = _bench_obj([{"name": "a", "us_per_call": 10.0,
-                       "derived": "tok_s=10"}])
-    bdir = tmp_path / "baselines"
-    bdir.mkdir()
-    (bdir / "BENCH_quick_cpu.json").write_text(json.dumps(base))
-    new_p = tmp_path / "new.json"
-    new_p.write_text(json.dumps(bad))
-    assert main([str(new_p), "--baselines", str(bdir)]) == 1
-    ok_p = tmp_path / "same.json"
-    ok_p.write_text(json.dumps(base))
-    assert main([str(ok_p), "--baselines", str(bdir)]) == 0
-    # --rtol override loosens the gate (the CI loose-CPU-tolerances mode)
-    assert main([str(new_p), "--baselines", str(bdir), "--rtol", "10"]) == 0
-
-
-def test_run_check_against_dir_no_baseline_passes(tmp_path):
-    from benchmarks.history import check_against_dir
-    ok, verdicts, path = check_against_dir(_bench_obj([]), str(tmp_path))
-    assert ok and verdicts == [] and "BENCH_quick_cpu" in path
-
-
-def test_committed_baseline_within_own_tolerances():
-    """The committed baseline must pass --check against itself with the
-    committed tolerance file (what CI's bench-regression job relies on)."""
-    from benchmarks.history import check_against_dir
-    bdir = os.path.join(_ROOT, "benchmarks", "baselines")
-    base_p = os.path.join(bdir, "BENCH_quick_cpu.json")
-    assert os.path.exists(base_p), "committed quick baseline missing"
-    with open(base_p) as f:
-        obj = json.load(f)
-    assert not validate_bench_obj(obj), validate_bench_obj(obj)
-    ok, verdicts, _ = check_against_dir(obj, bdir)
-    assert ok, [v.line() for v in verdicts if v.status == "regressed"]
-    assert verdicts, "baseline compared against nothing"
-
-
-# ---------------------------------------------------------------------------
 # adversarial validator inputs: diagnostics, never tracebacks
 # ---------------------------------------------------------------------------
 
@@ -531,72 +414,3 @@ def test_validate_trace_events_not_a_list(tmp_path):
     p.write_text(json.dumps({"traceEvents": {"oops": 1}}))
     errs = validate_trace(str(p))
     assert errs and "not a list" in errs[0]
-
-
-def test_validate_bench_obj_rejects_malformed_rows():
-    obj = _bench_obj([{"name": "a", "us_per_call": "fast"}])
-    assert any("us_per_call" in e for e in validate_bench_obj(obj))
-    assert validate_bench_obj("nope")           # not even a dict
-    assert not validate_bench_obj(
-        _bench_obj([{"name": "a", "us_per_call": 1.0, "derived": ""}]))
-
-
-# ---------------------------------------------------------------------------
-# report CLI renders from real artifacts
-# ---------------------------------------------------------------------------
-
-def test_report_renders_programs_anomalies_and_percentiles(tmp_path):
-    from repro.telemetry import report as report_mod
-
-    reg = telemetry.default_registry()
-    reg.counter("train/steps").inc(10)
-    h = reg.histogram("train/step_time_s")
-    for v in (0.01, 0.011, 0.012, 0.5):
-        h.observe(v)
-    reg.counter("anomaly/train_step_time/spikes").inc()
-    metrics.info("train/plan", algo="bsp")
-
-    @jax.jit
-    def f(x):
-        return x @ x
-
-    profile.capture("train/step", f, jnp.ones((32, 32)))
-    profile.observe("train/step", 0.01)
-
-    mpath = tmp_path / "m.jsonl"
-    telemetry.dump_metrics(str(mpath))
-    assert validate_metrics_jsonl(str(mpath)) == []
-
-    with trace.span("train/step"):
-        pass
-    tpath = tmp_path / "t.json"
-    trace.export(str(tpath))
-
-    bpath = tmp_path / "b.json"
-    bpath.write_text(json.dumps(_bench_obj(
-        [{"name": "x", "us_per_call": 5.0, "derived": "tok_s=9"}])))
-
-    out = tmp_path / "HEALTH.md"
-    rc = report_mod.main([str(mpath), "--trace", str(tpath),
-                          "--bench", str(bpath), "--out", str(out)])
-    assert rc == 0
-    md = out.read_text()
-    assert "# Run health report" in md
-    assert "## Programs" in md and "train/step" in md
-    assert "## anomaly" in md
-    assert "## train" in md and "p50=" in md and "p99=" in md
-    assert "## Top spans" in md
-    assert "## Bench rows" in md and "tok_s=9" in md
-
-
-def test_report_percentile_matches_live_histogram():
-    from repro.telemetry.report import _hist_percentile
-    from repro.telemetry.registry import Histogram
-
-    h = Histogram("x")
-    rng = np.random.default_rng(3)
-    for v in rng.lognormal(-4, 1, size=500):
-        h.observe(float(v))
-    rec = h.snapshot()
-    for q in (50, 90, 99):
-        assert _hist_percentile(rec, q) == pytest.approx(h.percentile(q))

@@ -1,4 +1,5 @@
-"""Exchanger equivalence on an 8-device host mesh.
+"""Exchanger equivalence, and the bytes each strategy puts on the wire,
+on an 8-device host mesh.
 
 Needs >1 device, so runs in a subprocess with
 XLA_FLAGS=--xla_force_host_platform_device_count=8 (keeps the main pytest
@@ -77,6 +78,34 @@ def run_mesh(mesh, axes, tag):
 
 run_mesh(make_mesh((8,), ("data",)), ("data",), "dp8")
 run_mesh(make_mesh((2, 4), ("pod", "data")), ("pod", "data"), "pod2x4")
+
+# what each strategy puts on the wire, read from the LOWERED module: the
+# CPU compiler widens bf16 collectives to f32, so the CPU-compiled text
+# would report asabf16 at asa's bytes
+from repro.core.exchanger import make_rs_plan, wire_summary
+from repro.roofline.analysis import parse_collectives
+mesh = make_mesh((8,), ("data",))
+jax.set_mesh(mesh)
+per_rank = {"w1": (256, 512), "w2": (512, 128), "b": (128,)}
+grads = {n: jax.ShapeDtypeStruct((8, *s), jnp.float32)
+         for n, s in per_rank.items()}
+plan = make_rs_plan({n: jax.ShapeDtypeStruct(s, jnp.float32)
+                     for n, s in per_rank.items()}, 8)
+for name in ["ar", "asa", "asa16", "asabf16", "asa8", "ring", "ring16",
+             "none"]:
+    ex = get_exchanger(name)
+    def f(gs):
+        per = {n: v[0] for n, v in gs.items()}
+        return {n: v[None] for n, v in ex.exchange(per, "data").items()}
+    fn = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=P("data"),
+                               out_specs=P("data"),
+                               axis_names=frozenset({"data"}),
+                               check_vma=False))
+    st = parse_collectives(fn.lower(grads).as_text(dialect="hlo"))
+    results[f"wire:{name}"] = {
+        "counts": st.counts, "hlo_bytes": st.total_bytes,
+        "model_bytes": wire_summary(ex, plan)["bytes_per_exchange"],
+        "buckets": plan.num_buckets, "small": len(plan.small)}
 print("RESULTS_JSON:" + json.dumps(results))
 """
 
@@ -115,6 +144,40 @@ def test_strategy_matches_mean_dp8(results, strategy):
 def test_strategy_matches_mean_multipod(results, strategy):
     r = results[f"pod2x4:{strategy}"]
     assert r["ok"], f"{strategy}: errors {r['errs']} > tol {r['tol']}"
+
+
+_ASA_KINDS = {"all-to-all", "all-gather", "all-reduce"}
+_RING_KINDS = {"collective-permute", "all-reduce"}
+_WIRE_KINDS = {"ar": {"all-reduce"}, "asa": _ASA_KINDS, "asa16": _ASA_KINDS,
+               "asabf16": _ASA_KINDS, "asa8": _ASA_KINDS,
+               "ring": _RING_KINDS, "ring16": _RING_KINDS, "none": set()}
+# narrow-wire strategies: (the fp32 strategy of their family, bytes ratio)
+_NARROWING = {"asa16": ("asa", 2), "asabf16": ("asa", 2), "asa8": ("asa", 4),
+              "ring16": ("ring", 2)}
+
+
+@pytest.mark.parametrize("strategy", list(_WIRE_KINDS))
+def test_lowered_exchange_moves_what_wire_summary_counts(results, strategy):
+    """``wire_summary`` (the ``exchange/bytes_wire`` counter's source)
+    against the bytes the lowered program sends, over two bucketed leaves
+    and one small one on 8 ranks. ``parse_collectives`` counts whole
+    result shapes where a rank sends (k-1)/k of them, so the module may
+    exceed the model by at most k/(k-1)."""
+    r = results[f"wire:{strategy}"]
+    assert (r["buckets"], r["small"]) == (2, 1)
+    assert set(r["counts"]) == _WIRE_KINDS[strategy], r["counts"]
+    if strategy not in ("ar", "none"):
+        # the small leaf is psum'd whole; every bucket takes the wire path
+        assert r["counts"]["all-reduce"] == 1, r["counts"]
+    k = 8
+    model, hlo = r["model_bytes"], r["hlo_bytes"]
+    assert model <= hlo <= model * k / (k - 1) * 1.001, (model, hlo)
+    if strategy in _NARROWING:
+        # the wire dtype really narrows what is sent (the bound above holds
+        # for any dtype, since the model reads the same transfer_dtype)
+        wide, ratio = _NARROWING[strategy]
+        assert results[f"wire:{wide}"]["hlo_bytes"] / hlo == pytest.approx(
+            ratio, rel=0.01), (wide, hlo)
 
 
 def test_bucketed_exchange_single_device():

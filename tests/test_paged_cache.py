@@ -1,10 +1,10 @@
 """Paged KV cache + hashed prefix caching: allocator lifecycle (refcounts,
 reservations, LRU eviction, OutOfPages), hash-collision safety, COW
 isolation, paged-vs-contiguous greedy bit-identity across GQA and
-absorbed-MLA layouts under request churn, compile-once with block tables,
-the paged flash-decode kernel vs gathered-lane oracle, paged pool
-shardings, and the SSM clean-lane regression for the O(d_state) admission
-reset."""
+absorbed-MLA layouts under request churn, slot density at fixed cache
+rows, compile-once with block tables, the paged flash-decode kernel vs
+gathered-lane oracle, paged pool shardings, and the SSM clean-lane
+regression for the O(d_state) admission reset."""
 import functools
 
 import jax
@@ -183,6 +183,36 @@ def test_paged_engine_bit_identical_to_contiguous(arch):
             f"{arch}: paged engine diverged from generate()"
     assert eng_p.trace_counts["decode"] == 1
     assert eng_p.trace_counts["prefill"] == 1
+
+
+def test_paged_pool_doubles_active_slots_at_fixed_cache_rows():
+    """Density at a fixed cache budget: the contiguous pool reserves
+    ``max_seq`` rows per slot whatever a request needs; the same rows cut
+    into pages (plus the null page) hold twice the slots at once, since
+    every request here needs far fewer than ``max_seq`` rows."""
+    cfg, model, params = _setup("llama3.2-1b")
+    slots, page = 4, 16
+    rng = np.random.RandomState(0)
+    prompts = [rng.randint(0, cfg.vocab_size, size=n).tolist()
+               for n in rng.randint(4, 24, size=2 * slots)]
+    kw = dict(max_seq=256, prefill_chunk=16)
+
+    def peak_active(eng):
+        for p in prompts:
+            eng.submit(p, 16)
+        peak = 0
+        while eng.sched.has_work():
+            eng.step()
+            peak = max(peak, eng.sched.num_active)
+        return peak
+
+    eng_c = Engine(model, params, max_slots=slots, page_size=0, **kw)
+    cache_rows = slots * eng_c.max_seq
+    eng_p = Engine(model, params, max_slots=2 * slots, page_size=page,
+                   num_pages=cache_rows // page + 1, **kw)
+    peak_c = peak_active(eng_c)
+    assert peak_c == slots
+    assert peak_active(eng_p) == 2 * peak_c
 
 
 def test_prefix_hit_skips_prefill_and_stays_bit_identical():

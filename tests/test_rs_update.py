@@ -6,7 +6,8 @@ exchange-then-update path for every strategy on an 8-way host mesh — with
 deliberately non-divisible leaf sizes so the pad/shard/unpad plumbing is
 exercised. Lossy-wire strategies (fp16/int8) differ only by where the
 rounding lands (reduced gradient vs gathered parameters), so they get
-per-strategy tolerances.
+per-strategy tolerances. The overlapped step's compiled schedule must
+put each microbatch's reduce-scatter beside the next one's backward dots.
 
 Runs in a subprocess with XLA_FLAGS=--xla_force_host_platform_device_count=8
 (keeps the main pytest process at 1 device per the dry-run contract).
@@ -65,7 +66,11 @@ def run(opt, strat, **kw):
                                  constant(0.05), mesh, **kw))
     for i in range(STEPS):
         state, metrics = step(state, batch, jax.random.key(100 + i))
-    return state
+    # one program in flight at a time: the CPU backend runs all 8 virtual
+    # devices' collectives on one shared thread pool, and two independent
+    # runs in flight at once can each hold part of it while waiting for
+    # the rest (a rendezvous timeout under load)
+    return jax.block_until_ready(state)
 
 
 def rel_err(a, b):
@@ -135,6 +140,33 @@ sgd_n = sgd_momentum(momentum=0.9, weight_decay=5e-4, nesterov=True)
 a = run(sgd_n, "asa16", sharded_update=True, fuse_rs_update=True)
 b = run(sgd_n, "asa16", sharded_update=True, fuse_rs_update=False)
 results["fused_vs_flat"] = {"errs": rel_err(a, b)}
+
+# the compiled step's schedule (asa16, 4 microbatches): only the overlapped
+# body holds a reduce-scatter that no backward dot of its iteration feeds.
+# Every gradient here passes through a dot: a leaf whose gradient does not
+# (b1 above) is loop-invariant, XLA hoists its reduce-scatter out of the
+# scan, and that collective reads as independent whatever the schedule.
+from repro.roofline.analysis import overlap_evidence
+
+def init3(key):
+    return {k: v for k, v in init(key).items() if k in ("w1", "w2")}
+
+def loss3(params, batch, rng=None, unroll=False):
+    loss = 0.5 * jnp.mean(jnp.square(
+        jnp.tanh(batch["x"] @ params["w1"]) @ params["w2"]))
+    return loss, {"loss": loss, "aux": jnp.zeros(())}
+
+m3 = Model(cfg=None, init=init3, loss_fn=loss3, forward=None)
+for bb in (0, 1 << 20):
+    st = init_sharded_train_state(m3, sgd, jax.random.key(0), mesh,
+                                  bucket_bytes=bb)
+    for mode, kw in (("serial", dict(sharded_update=True)),
+                     ("overlap", dict(overlap="buckets"))):
+        step = jax.jit(make_bsp_step(
+            m3, sgd, get_exchanger("asa16"), constant(0.05), mesh,
+            microbatches=4, bucket_bytes=bb, **kw))
+        txt = step.lower(st, batch, jax.random.key(0)).compile().as_text()
+        results[f"evidence:{mode}:{bb}"] = overlap_evidence(txt)
 print("RESULTS_JSON:" + json.dumps(results))
 """
 
@@ -196,6 +228,17 @@ def test_adamw_sharded_matches(results):
 def test_fused_kernel_matches_flat_update(results):
     errs = results["fused_vs_flat"]["errs"]
     assert max(errs.values()) <= 1e-6, errs
+
+
+@pytest.mark.parametrize("bucket_bytes", [0, 1 << 20])
+@pytest.mark.parametrize("mode", ["serial", "overlap"])
+def test_overlap_puts_reduce_scatter_beside_backward_dots(results, mode,
+                                                          bucket_bytes):
+    """``overlap="buckets"`` reduce-scatters microbatch i-1's gradients in
+    the scan body, beside microbatch i's backward dots; the serial sharded
+    update issues every reduce-scatter after the loop."""
+    ev = results[f"evidence:{mode}:{bucket_bytes}"]
+    assert ev["comm_independent_of_dots"] == (mode == "overlap"), ev
 
 
 def test_rs_plan_invariants():

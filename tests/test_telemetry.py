@@ -538,7 +538,7 @@ def test_serve_outputs_identical_telemetry_on_vs_off():
 
 def test_serve_stats_live_with_telemetry_off():
     """EngineStats owns a private registry: TTFT/queue-wait/throughput must
-    work with the global switch off (bench_serve depends on this)."""
+    work with the global switch off."""
     telemetry.set_enabled(False)
     outs, eng = _serve_run()
     st = eng.stats
