@@ -26,6 +26,15 @@ updates and parameter all-gathers interleave too. Note the tradeoff:
 overlap exchanges every microbatch's gradient separately (m× wire volume,
 hidden behind backprop) while the serialized path exchanges the
 accumulated gradient once.
+
+Each microbatch's receive is accumulated in the shape the reduce-scatter
+returns it (``Exchanger.reduce_scatter``): a **shaped** bucket — one leaf
+whose leading dimension k divides, every bucket of a ``bucket_bytes=0``
+plan at k = 1 — stays in its leaf's own layout through the scan, so no
+relayout of the gradient runs per microbatch. Each bucket is flattened
+once per step, after the scan (or right after the all-to-all on the
+serialized path), into the flat shard the update and the optimizer state
+use; the state's layout does not depend on it.
 """
 from __future__ import annotations
 
@@ -36,6 +45,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from repro import telemetry
 from repro.core.exchanger import (Exchanger, RSPlan, default_chunk_sum,
                                   make_rs_plan, norm_axes, param_wire_dtype)
 from repro.models.registry import Model
@@ -148,11 +158,15 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
     same ``bucket_bytes``. ``overlap="buckets"`` additionally
     double-buffers the microbatch scan (see module docstring); it implies
     ``sharded_update`` and needs ``microbatches >= 2`` to overlap
-    anything. ``fuse_rs_update`` selects the Pallas fused
-    dequant+sum+update kernel on the raw alltoall receives (needs a
-    single-axis asa-family strategy and an optimizer with
+    anything; its accumulators hold each bucket's receive in its
+    ``reduce_scatter`` shape (a shaped bucket's in its leaf's layout) and
+    are flattened once, after the scan. ``fuse_rs_update`` selects the
+    Pallas fused dequant+sum+update kernel on the raw alltoall receives
+    (needs a single-axis asa-family strategy and an optimizer with
     ``rs_fused_update``; None = auto: on when kernels run compiled — TPU —
-    off in interpreter mode where the jnp flat update is faster).
+    off in interpreter mode where the jnp flat update is faster). The
+    gauges ``exchange/shaped_buckets`` and ``exchange/flat_buckets`` count
+    the plan's buckets of each kind when the step is built.
 
     ``grad_norm=True`` adds the post-exchange global gradient norm to the
     step metrics — the telemetry layer's single *in-graph* opt-in (it adds
@@ -260,6 +274,9 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
                 f"an optimizer with rs_fused_update (got {exchanger.name!r}"
                 f" / {optimizer.name!r})")
         nb = plan.num_buckets
+        n_shaped = sum(b.shaped for b in plan.buckets)
+        telemetry.metrics.gauge("exchange/shaped_buckets").set(n_shaped)
+        telemetry.metrics.gauge("exchange/flat_buckets").set(nb - n_shaped)
 
         def shard_wd_mask(b, start):
             # 1.0 where the element's original leaf is >=2-D (weight decay
@@ -278,7 +295,9 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
 
         @jax.named_scope("exchange")
         def rs_accum(grads):
-            """RS one microbatch's grads to fp32 accumulables."""
+            """RS one microbatch's grads to fp32 accumulables, each in the
+            shape ``reduce_scatter`` returns (a shaped bucket's in its
+            leaf's layout, so adding it up moves no data around)."""
             res, _ = exchanger.reduce_scatter(grads, axes, sum_fn=sum_fn,
                                               plan=plan, raw=use_raw)
             if use_raw:
@@ -308,11 +327,11 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
                     return _loss_and_grad(model, params, mbatch, rng, unroll)
 
                 (l0, met0), g0 = one_grad(mb0)
-                acc0 = [jnp.zeros((plan.k, b.shard_len) if use_raw
-                                  else (b.shard_len,), jnp.float32)
-                        for b in plan.buckets]
-                accf0 = [jnp.zeros(plan.shapes[i], jnp.float32)
-                         for i in plan.small]
+                # each accumulator takes its receive's shape: a shaped
+                # bucket's stays in its leaf's layout through the scan
+                acc0, accf0 = jax.tree.map(
+                    lambda r: jnp.zeros(r.shape, r.dtype),
+                    jax.eval_shape(rs_accum, g0))
 
                 def body(carry, mbatch):
                     acc, accf, pending, loss_s, aux_s = carry
@@ -337,11 +356,13 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
                 loss = loss_s / m
                 metrics = {"loss": loss, "aux": aux_s / m}
                 fulls = [a / m for a in accf]
+                # flattened once per step, after the scan
                 if use_raw:
-                    chunks, scales = acc, [None] * nb
+                    chunks = [a.reshape(plan.k, -1) for a in acc]
+                    scales = [None] * nb
                     scale = 1.0 / (plan.k * m)
                 else:
-                    shards = [a / m for a in acc]
+                    shards = [a.reshape(-1) / m for a in acc]
             else:
                 (loss, metrics), grads = grad_of(params, batch, rng)
                 with jax.named_scope("exchange"):
@@ -349,11 +370,11 @@ def make_bsp_step(model: Model, optimizer: Optimizer, exchanger: Exchanger,
                         grads, axes, sum_fn=sum_fn, plan=plan, raw=use_raw)
                 fulls = res["full"]
                 if use_raw:
-                    chunks = res["chunks"]
+                    chunks = [c.reshape(plan.k, -1) for c in res["chunks"]]
                     scales = res["scales"] or [None] * nb
                     scale = 1.0 / plan.k
                 else:
-                    shards = res["shards"]
+                    shards = [s.reshape(-1) for s in res["shards"]]
 
             p_leaves = jax.tree.flatten(params)[0]
             p_smalls = [p_leaves[i] for i in plan.small]

@@ -36,6 +36,15 @@ per leaf by default, or DDP-style multi-leaf buckets of up to
 ``bucket_bytes``. Leaves smaller than ``_SMALL_LEAF`` elements are psum'd
 whole and updated replicated — chunking overhead dominates there.
 
+The reduce-scatter works on each bucket's ``(k, ...)`` view, row i being
+rank i's chunk (``Exchanger.split``). A **shaped** bucket — one leaf whose
+leading dimension ``k`` divides — is its leaf split on that dimension,
+``(k, d0 // k, *rest)``, so its shard comes back in the leaf's own shape
+and no relayout runs before the wire; every other bucket is the flat
+bucket as ``(k, shard_len)``. Row-major, row i holds the same elements in
+the same order either way, so the flat shard is the shaped one reshaped:
+callers flatten once, where the update needs it.
+
 NOTE: flattening assumes gradient leaves are *replicated* over any
 model-parallel mesh axes inside the shard_map body — the invariant the
 BSP path maintains (``repro.dist.state_shardings`` replicates train
@@ -112,6 +121,8 @@ class BucketSpec:
     sizes: tuple[int, ...]       # flat element counts, same order
     shard_len: int               # per-rank shard extent
     padded: int                  # k * shard_len
+    shaped: bool                 # one leaf, leading dim divisible by k:
+                                 # exchanged in the leaf's own shape
 
 
 @dataclass(frozen=True)
@@ -169,37 +180,41 @@ def make_rs_plan(tree, k: int, bucket_bytes: int = 0,
         sizes = tuple(_leaf_size(leaves[i]) for i in g)
         total = sum(sizes)
         shard_len = -(-total // k)
-        buckets.append(BucketSpec(tuple(g), sizes, shard_len, shard_len * k))
+        shaped = len(g) == 1 and shapes[g[0]][0] % k == 0
+        buckets.append(BucketSpec(tuple(g), sizes, shard_len, shard_len * k,
+                                  shaped))
     return RSPlan(k, tuple(buckets), tuple(small), treedef, shapes, dtypes)
 
 
 # ---------------------------------------------------------------------------
-# per-bucket halves on flat fp32 arrays (inside shard_map)
+# per-bucket halves (inside shard_map): the RS takes a bucket's (k, ...)
+# fp32 view and returns rank i's shard, row i's shape; the AG takes and
+# returns flat fp32 arrays
 # ---------------------------------------------------------------------------
 
 def _quant_rows(cf):
-    """Per-row absmax int8 quantization: (k, s) fp32 -> (q int8, scale (k,1))."""
-    scale = jnp.max(jnp.abs(cf), axis=1, keepdims=True) / 127.0 + 1e-12
+    """Per-row absmax int8 quantization: (k, ...) fp32 -> (q int8, scale
+    (k, 1, ...)) with one scale per row."""
+    scale = jnp.max(jnp.abs(cf), axis=tuple(range(1, cf.ndim)),
+                    keepdims=True) / 127.0 + 1e-12
     q = jnp.clip(jnp.round(cf / scale), -127, 127).astype(jnp.int8)
     return q, scale
 
 
-def _rs_ar(flat, axes, inv_k, sum_fn, transfer_dtype):
+def _rs_ar(chunks, axes, inv_k, sum_fn, transfer_dtype):
     """psum_scatter over the rs axis (+ psum over lead axes): true HLO
     reduce-scatter, fp32 on the wire."""
     lead, ax = _split_axes(axes)
-    s = jax.lax.psum_scatter(flat, ax, scatter_dimension=0, tiled=True)
+    s = jax.lax.psum_scatter(chunks, ax, scatter_dimension=0)
     if lead:
         s = jax.lax.psum(s, tuple(lead))
     return s * inv_k
 
 
-def _rs_asa(flat, axes, inv_k, sum_fn, transfer_dtype):
+def _rs_asa(chunks, axes, inv_k, sum_fn, transfer_dtype):
     """Alltoall -> local fp32 sum (paper Fig 2), optional lead-axes psum of
     the 1/k shard (the hierarchical/DCN leg)."""
     lead, ax = _split_axes(axes)
-    k = jax.lax.axis_size(ax)
-    chunks = flat.reshape(k, -1)
     if transfer_dtype == jnp.int8 and lead:
         transfer_dtype = jnp.float16   # int8 scaling not plumbed across pods
     if transfer_dtype == jnp.int8:
@@ -217,16 +232,15 @@ def _rs_asa(flat, axes, inv_k, sum_fn, transfer_dtype):
     return s * inv_k
 
 
-def _rs_asa_raw(flat, axes, sum_fn, transfer_dtype):
+def _rs_asa_raw(chunks, axes, sum_fn, transfer_dtype):
     """Transfer-only RS half: the received per-rank chunks BEFORE summation,
     so a fused kernel can do dequant + fp32 sum + update in one VMEM pass.
 
-    Returns ``(recv (k, s) wire-dtype, scales (k, 1) | None)``; the caller
-    owns the mean divisor. Single-axis only."""
+    Returns ``(recv (k, ...) wire-dtype, scales (k, 1, ...) | None)`` in
+    the shape of ``chunks``; the caller owns the mean divisor. Single-axis
+    only."""
     lead, ax = _split_axes(axes)
     assert not lead, "raw reduce-scatter is single-axis (intra-pod) only"
-    k = jax.lax.axis_size(ax)
-    chunks = flat.reshape(k, -1)
     if transfer_dtype == jnp.int8:
         q, scale = _quant_rows(chunks)
         recv = jax.lax.all_to_all(q, ax, split_axis=0, concat_axis=0)
@@ -238,26 +252,25 @@ def _rs_asa_raw(flat, axes, sum_fn, transfer_dtype):
     return recv, None
 
 
-def _rs_ring(flat, axes, inv_k, sum_fn, transfer_dtype):
+def _rs_ring(chunks, axes, inv_k, sum_fn, transfer_dtype):
     """Ring reduce-scatter via collective_permute; rank i ends holding
     chunk i fully reduced (aligned with the AG/update shard layout)."""
     lead, ax = _split_axes(axes)
     if lead:   # cross-pod: stage hierarchically like asa/hier
-        return _rs_asa(flat, axes, inv_k, sum_fn, transfer_dtype)
+        return _rs_asa(chunks, axes, inv_k, sum_fn, transfer_dtype)
     k = jax.lax.axis_size(ax)
     if k == 1:
-        return flat * inv_k
-    x = flat.reshape(k, -1)
+        return chunks[0] * inv_k
     idx = jax.lax.axis_index(ax)
     fwd = [(i, (i + 1) % k) for i in range(k)]
     # at step s rank i sends its partial of chunk (i-s-1)%k and receives
     # chunk (i-s-2)%k, adding its local copy; after k-1 steps rank i holds
     # chunk i fully reduced.
-    acc = jnp.take(x, (idx - 1) % k, axis=0)
+    acc = jnp.take(chunks, (idx - 1) % k, axis=0)
     for s in range(k - 1):
         acc_t = acc.astype(transfer_dtype) if transfer_dtype is not None else acc
         recv = jax.lax.ppermute(acc_t, ax, fwd).astype(jnp.float32)
-        acc = recv + jnp.take(x, (idx - s - 2) % k, axis=0)
+        acc = recv + jnp.take(chunks, (idx - s - 2) % k, axis=0)
     return acc * inv_k
 
 
@@ -304,6 +317,15 @@ _RS_FNS = {"ar": _rs_ar, "asa": _rs_asa, "ring": _rs_ring}
 _AG_FNS = {"ar": _ag_flat, "asa": _ag_flat, "ring": _ag_ring}
 
 
+def _flat_bucket(leaves, b: BucketSpec):
+    """Bucket ``b``'s leaves, flattened, cast to fp32, concatenated and
+    zero-padded to ``b.padded``."""
+    f = jnp.concatenate(
+        [leaves[i].reshape(-1).astype(jnp.float32) for i in b.leaves])
+    pad = b.padded - f.shape[0]
+    return jnp.pad(f, (0, pad)) if pad else f
+
+
 # ---------------------------------------------------------------------------
 # pytree-level exchanger
 # ---------------------------------------------------------------------------
@@ -330,15 +352,26 @@ class Exchanger:
     def pack(tree, plan: RSPlan):
         """-> (flat fp32 padded bucket list, small-leaf list, leaves)."""
         leaves = jax.tree.flatten(tree)[0]
-        flats = []
-        for b in plan.buckets:
-            f = jnp.concatenate(
-                [leaves[i].reshape(-1).astype(jnp.float32) for i in b.leaves])
-            pad = b.padded - f.shape[0]
-            if pad:
-                f = jnp.pad(f, (0, pad))
-            flats.append(f)
+        flats = [_flat_bucket(leaves, b) for b in plan.buckets]
         return flats, [leaves[i] for i in plan.small], leaves
+
+    @staticmethod
+    def split(tree, plan: RSPlan):
+        """-> (each bucket's fp32 ``(k, ...)`` view, small-leaf list).
+
+        Row i is what rank i reduces. A shaped bucket is its leaf split on
+        the leading dimension, ``(k, d0 // k, *rest)``: no relayout. Any
+        other bucket is ``pack``'s flat bucket as ``(k, shard_len)``.
+        Row-major, row i holds the same elements in the same order."""
+        leaves = jax.tree.flatten(tree)[0]
+        views = []
+        for b in plan.buckets:
+            if b.shaped:
+                leaf = leaves[b.leaves[0]].astype(jnp.float32)
+                views.append(leaf.reshape(plan.k, -1, *leaf.shape[1:]))
+            else:
+                views.append(_flat_bucket(leaves, b).reshape(plan.k, -1))
+        return views, [leaves[i] for i in plan.small]
 
     @staticmethod
     def unpack(flats, smalls, plan: RSPlan):
@@ -364,27 +397,34 @@ class Exchanger:
 
         Returns ``({"shards", "full"}, plan)`` — or with ``raw=True`` (asa
         family only) ``{"chunks", "scales", "full"}`` where chunks are the
-        un-summed per-rank receives for the fused RS+update kernel."""
+        un-summed per-rank receives for the fused RS+update kernel.
+
+        Each bucket comes back in the shape of its ``split`` row: a shaped
+        bucket's shard is ``(d0 // k, *rest)`` and its chunks ``(k, d0 //
+        k, *rest)``, the leaf's own layout, so a caller that accumulates
+        several receives does so with no relayout; any other bucket's is
+        ``(shard_len,)`` / ``(k, shard_len)``. ``s.reshape(-1)`` /
+        ``c.reshape(k, -1)`` gives the flat form of either, bit for bit."""
         if self.kind == "none":
             raise ValueError("'none' exchanger has no reduce_scatter half")
         if plan is None:
             plan = self.plan_for(grads, axis, bucket_bytes)
         inv_k = 1.0 / _axis_size(axis)
-        flats, smalls, _ = self.pack(grads, plan)
+        views, smalls = self.split(grads, plan)
         full = [jax.lax.psum(s.astype(jnp.float32), axis) * inv_k
                 for s in smalls]
         if raw:
             if not self.supports_raw:
                 raise ValueError(
                     f"raw reduce-scatter unsupported for {self.name!r}")
-            pairs = [_rs_asa_raw(f, axis, sum_fn, self.transfer_dtype)
-                     for f in flats]
+            pairs = [_rs_asa_raw(v, axis, sum_fn, self.transfer_dtype)
+                     for v in views]
             return {"chunks": [p[0] for p in pairs],
                     "scales": [p[1] for p in pairs if p[1] is not None],
                     "full": full}, plan
         rs = _RS_FNS[self.kind]
-        shards = [rs(f, axis, inv_k, sum_fn, self.transfer_dtype)
-                  for f in flats]
+        shards = [rs(v, axis, inv_k, sum_fn, self.transfer_dtype)
+                  for v in views]
         return {"shards": shards, "full": full}, plan
 
     def all_gather(self, shards, plan: RSPlan, axis, *,
@@ -432,7 +472,8 @@ class Exchanger:
             return self.unpack(red, full, plan)
         res, plan = self.reduce_scatter(grads, axis, sum_fn=sum_fn,
                                         plan=plan)
-        flats = self.all_gather(res["shards"], plan, axis)
+        flats = self.all_gather([s.reshape(-1) for s in res["shards"]],
+                                plan, axis)
         return self.unpack(flats, res["full"], plan)
 
 

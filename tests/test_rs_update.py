@@ -65,12 +65,17 @@ def run(opt, strat, **kw):
     step = jax.jit(make_bsp_step(model, opt, get_exchanger(strat),
                                  constant(0.05), mesh, **kw))
     for i in range(STEPS):
-        state, metrics = step(state, batch, jax.random.key(100 + i))
+        state = one_step(step, state, batch, jax.random.key(100 + i))
+    return state
+
+
+def one_step(step, state, batch, key):
     # one program in flight at a time: the CPU backend runs all 8 virtual
-    # devices' collectives on one shared thread pool, and two independent
-    # runs in flight at once can each hold part of it while waiting for
-    # the rest (a rendezvous timeout under load)
-    return jax.block_until_ready(state)
+    # devices' collectives on one shared thread pool (8 threads here), and
+    # two programs in flight at once, even two steps in a row, can each
+    # hold part of it while waiting for the rest (a rendezvous timeout
+    # under load)
+    return jax.block_until_ready(step(state, batch, key)[0])
 
 
 def rel_err(a, b):
@@ -130,7 +135,7 @@ st2 = init_sharded_train_state(m2, opt2, jax.random.key(0), mesh)
 step2 = jax.jit(make_bsp_step(m2, opt2, get_exchanger("asa16"),
                               constant(0.2), mesh, sharded_update=True))
 for i in range(100):
-    st2, _ = step2(st2, batch, jax.random.key(i))
+    st2 = one_step(step2, st2, batch, jax.random.key(i))
 results["master_accum"] = {
     "delta": float(1.0 - np.asarray(st2["params"]["w"]).mean())}
 
@@ -167,6 +172,150 @@ for bb in (0, 1 << 20):
             microbatches=4, bucket_bytes=bb, **kw))
         txt = step.lower(st, batch, jax.random.key(0)).compile().as_text()
         results[f"evidence:{mode}:{bb}"] = overlap_evidence(txt)
+
+# Shaped buckets: a one-leaf bucket whose leading dim k divides is
+# exchanged in its leaf's own shape. Leaves: "a" (64, 48) and "d" (48, 33)
+# divide by 1 and 4, "c" (33, 40) only by 1, so at k = 4 it stays flat.
+from repro import telemetry
+from repro.core.exchanger import Exchanger, default_chunk_sum, make_rs_plan
+
+telemetry.set_enabled(True)
+
+
+def init5(key):
+    r = lambda i, s: jax.random.normal(jax.random.fold_in(key, i), s) * 0.1
+    return {"a": r(0, (64, 48)), "c": r(1, (33, 40)), "d": r(2, (48, 33))}
+
+
+def loss5(params, batch, rng=None, unroll=False):
+    h = jnp.tanh(jnp.tanh(batch["x"] @ params["a"]) @ params["d"])
+    loss = 0.5 * jnp.mean(jnp.square(h @ params["c"]))
+    return loss, {"loss": loss, "aux": jnp.zeros(())}
+
+
+m5 = Model(cfg=None, init=init5, loss_fn=loss5, forward=None)
+batch5 = {"x": np.random.default_rng(1).normal(0, 1, (32, 64)).astype(
+    np.float32)}
+
+
+def flat_receives(ex, tree, plan, raw, ax):
+    # the flat receive of each bucket, by the path each strategy took
+    # before buckets kept their leaf's shape: the reference
+    inv_k = 1.0 / plan.k
+    out = []
+    for f in Exchanger.pack(tree, plan)[0]:
+        c = f.reshape(plan.k, -1)
+        if ex.kind == "ar":
+            out.append(jax.lax.psum_scatter(f, ax, scatter_dimension=0,
+                                            tiled=True) * inv_k)
+            continue
+        if ex.transfer_dtype == jnp.int8:
+            scale = jnp.max(jnp.abs(c), axis=1, keepdims=True) / 127.0 + 1e-12
+            q = jnp.clip(jnp.round(c / scale), -127, 127).astype(jnp.int8)
+            r = jax.lax.all_to_all(q, ax, 0, 0)
+            rs = jax.lax.all_to_all(scale, ax, 0, 0)
+            out.append((r, rs) if raw else
+                       jnp.sum(r.astype(jnp.float32) * rs, axis=0) * inv_k)
+            continue
+        if ex.transfer_dtype is not None:
+            c = c.astype(ex.transfer_dtype)
+        r = jax.lax.all_to_all(c, ax, 0, 0)
+        out.append(r if raw else default_chunk_sum(r) * inv_k)
+    return out
+
+
+def flat_reduce_scatter(self, grads, axis, *, sum_fn=default_chunk_sum,
+                        bucket_bytes=0, plan=None, raw=False):
+    # Exchanger.reduce_scatter on flat buckets (float wires only)
+    plan = plan or self.plan_for(grads, axis, bucket_bytes)
+    full = [jax.lax.psum(s.astype(jnp.float32), axis) * (1.0 / plan.k)
+            for s in self.pack(grads, plan)[1]]
+    recv = flat_receives(self, grads, plan, raw, axis)
+    if raw:
+        return {"chunks": recv, "scales": [], "full": full}, plan
+    return {"shards": recv, "full": full}, plan
+
+
+def bits_equal(x, y):
+    x, y = np.asarray(x), np.asarray(y)
+    return x.shape == y.shape and x.dtype == y.dtype and x.tobytes() == y.tobytes()
+
+
+from jax.sharding import PartitionSpec as P
+grads5 = jax.tree.map(lambda l: np.stack([np.asarray(l) * (1.0 + 0.37 * i)
+                                          for i in range(8)]),
+                      init5(jax.random.key(3)))
+for k in (1, 4):
+    mesh_k = make_mesh((k,), ("data",), devices=jax.devices()[:k])
+    # rank i's gradient is grads5[...][i]
+    g_k = jax.tree.map(lambda l: l[:k].reshape(k * l.shape[1], *l.shape[2:]),
+                       grads5)
+    plan5 = make_rs_plan(init5(jax.random.key(0)), k)
+    results[f"shaped_plan:{k}"] = [b.shaped for b in plan5.buckets]
+    for strat, raw in (("asa", False), ("asa16", False), ("asa16", True),
+                       ("asa8", False), ("asa8", True), ("ar", False)):
+        ex = get_exchanger(strat)
+
+        def both(g, ex=ex, raw=raw):
+            res, _ = ex.reduce_scatter(g, "data", plan=plan5, raw=raw)
+            if raw:
+                new = [c.reshape(k, -1) for c in res["chunks"]]
+                new = (list(zip(new, [s.reshape(k, 1)
+                                      for s in res["scales"]]))
+                       if res["scales"] else new)
+            else:
+                new = [s.reshape(-1) for s in res["shards"]]
+            return new, flat_receives(ex, g, plan5, raw, "data")
+
+        with jax.set_mesh(mesh_k):
+            new, old = jax.jit(jax.shard_map(
+                both, mesh=mesh_k, in_specs=P("data"), out_specs=P("data"),
+                check_vma=False))(g_k)
+        results[f"shaped_rs:{k}:{strat}:{'raw' if raw else 'sum'}"] = all(
+            bits_equal(x, y) for x, y in zip(jax.tree.leaves(new),
+                                             jax.tree.leaves(old)))
+
+    # three overlapped steps on shaped buckets against the flat reference
+    for mode, raw, opt in (("raw", True, sgd), ("flat_update", False, sgd),
+                           ("flat_update_nowd", False,
+                            sgd_momentum(momentum=0.9, weight_decay=0.0))):
+        states = []
+        for rs in (Exchanger.reduce_scatter, flat_reduce_scatter):
+            orig = Exchanger.reduce_scatter
+            Exchanger.reduce_scatter = rs
+            try:
+                with jax.set_mesh(mesh_k):
+                    st = init_sharded_train_state(m5, opt, jax.random.key(0),
+                                                  mesh_k)
+                    step = jax.jit(make_bsp_step(
+                        m5, opt, get_exchanger("asa16"), constant(0.05),
+                        mesh_k, microbatches=4, overlap="buckets",
+                        fuse_rs_update=raw))
+                    for i in range(STEPS):
+                        st = one_step(step, st, batch5,
+                                      jax.random.key(100 + i))
+                    states.append(st["opt"])
+            finally:
+                Exchanger.reduce_scatter = orig
+        new, old = states
+        pairs = list(zip(new["master"], old["master"])) + [
+            (x["m"], y["m"]) for x, y in zip(new["buckets"], old["buckets"])]
+        results[f"shaped_steps:{k}:{mode}"] = {
+            "bitwise": all(bits_equal(x, y) for x, y in pairs),
+            # largest gap in float32 ulps of the array's largest magnitude
+            "ulps": max(float(np.abs(np.asarray(x) - np.asarray(y)).max()
+                              / np.spacing(np.abs(np.asarray(y)).max()))
+                        for x, y in pairs),
+            "moved": bool(np.abs(np.asarray(new["buckets"][0]["m"])).max()
+                          > 0)}
+
+    # the gauges count the plan's shaped and flat buckets when a step is built
+    for bb in (0, 1 << 20):
+        make_bsp_step(m5, sgd, get_exchanger("asa16"), constant(0.05), mesh_k,
+                      microbatches=4, overlap="buckets", bucket_bytes=bb)
+        results[f"gauges:{k}:{bb}"] = [
+            telemetry.metrics.get(f"exchange/{n}_buckets").value
+            for n in ("shaped", "flat")]
 print("RESULTS_JSON:" + json.dumps(results))
 """
 
@@ -239,6 +388,55 @@ def test_overlap_puts_reduce_scatter_beside_backward_dots(results, mode,
     update issues every reduce-scatter after the loop."""
     ev = results[f"evidence:{mode}:{bucket_bytes}"]
     assert ev["comm_independent_of_dots"] == (mode == "overlap"), ev
+
+
+def test_plan_marks_shaped_buckets(results):
+    """A one-leaf bucket is shaped when k divides its leading dim: at
+    k = 1 every leaf, at k = 4 "a" (64, ...) and "d" (48, ...) but not
+    "c" (33, ...). Leaves flatten alphabetically: a, c, d."""
+    assert results["shaped_plan:1"] == [True, True, True]
+    assert results["shaped_plan:4"] == [True, False, True]
+
+
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("strategy,mode", [
+    ("asa", "sum"), ("asa16", "sum"), ("asa16", "raw"), ("asa8", "sum"),
+    ("asa8", "raw"), ("ar", "sum")])
+def test_shaped_receive_flattens_to_the_flat_receive(results, k, strategy,
+                                                     mode):
+    """The shaped shard reshaped to (-1,), and the shaped raw chunks to
+    (k, -1), equal the flat bucket's receive bit for bit: row i of the
+    leaf split on its leading dim is row i of the flat bucket."""
+    assert results[f"shaped_rs:{k}:{strategy}:{mode}"]
+
+
+@pytest.mark.parametrize("k", [1, 4])
+@pytest.mark.parametrize("mode", ["raw", "flat_update", "flat_update_nowd"])
+def test_overlap_on_shaped_buckets_matches_flat_accumulation(results, k,
+                                                             mode):
+    """Three overlapped steps (asa16, 4 microbatches) that accumulate the
+    receives in their leaf's shape leave the same master shards and
+    momenta as accumulating every bucket flat: bit for bit through the
+    fused kernel and through the jnp update without weight decay. With
+    it, the CPU backend may contract ``g + wd * mask * p`` into one
+    fused multiply-add for one layout and not the other, a rounding of at
+    most one float32 ulp per step."""
+    r = results[f"shaped_steps:{k}:{mode}"]
+    assert r["moved"], r
+    if mode == "flat_update":
+        assert r["ulps"] <= 3.0, r
+    else:
+        assert r["bitwise"], r
+
+
+@pytest.mark.parametrize("k,bucket_bytes,want", [
+    (1, 0, [3, 0]), (4, 0, [2, 1]), (1, 1 << 20, [0, 1]),
+    (4, 1 << 20, [0, 1])])
+def test_gauges_count_shaped_and_flat_buckets(results, k, bucket_bytes,
+                                              want):
+    """``exchange/shaped_buckets`` / ``exchange/flat_buckets`` are set
+    when the step is built; a multi-leaf bucket is flat."""
+    assert results[f"gauges:{k}:{bucket_bytes}"] == want
 
 
 def test_rs_plan_invariants():

@@ -198,3 +198,105 @@ def test_fused_rs_update_int8_compiles(one_chip):
     assert "tpu_custom_call" in txt
     assert not {"copy", "transpose"} & set(
         _operand_producers(txt, "fused_rs_update")[2:])   # p, m, mask, lr
+
+
+# two dense layers, one weight gradient a 2M-element (1024, 2048) leaf;
+# every leaf is a bucket (each > 1024 elements), all shaped at k = 1
+_MLP = {"w1": (1024, 2048), "b1": (2048,), "w2": (2048, 256)}
+_RELAYOUT = re.compile(r"(copy|reshape|convert_\w*fusion)(\.\d+)?$")
+
+
+def _computations(txt):
+    """Compiled HLO text -> {computation name: [(instruction name, opcode,
+    element count of its array output or None)]}."""
+    comps, cur = {}, None
+    for line in txt.splitlines():
+        head = re.match(r"^(?:ENTRY )?%(\S+) .*\{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            m = re.match(r"^\s*(?:ROOT )?%(\S+) = (.*?) ([\w-]+)\(", line)
+            if m:
+                arr = re.fullmatch(r"\w+\[([\d,]*)\]\S*", m.group(2))
+                size = arr and int(np.prod(
+                    [int(d) for d in arr.group(1).split(",") if d]))
+                cur.append((m.group(1), m.group(3), size))
+    return comps
+
+
+def test_bsp_step_accumulates_buckets_in_their_leaf_shape(topo, monkeypatch):
+    """The overlapped BSP step (asa16, 4 microbatches, one described v5e)
+    adds each microbatch's receive in its leaf's own layout: the scan's
+    body holds no top-level ``copy``, ``reshape`` or ``convert_*_fusion``
+    of a gradient-sized array. The flat view the fused update reads is made
+    after the scan, at most once per bucket on the way from the
+    accumulator to the kernel."""
+    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from repro.core import (get_exchanger, init_sharded_train_state,
+                            make_bsp_step)
+    from repro.launch.mesh import make_mesh
+    from repro.models.registry import Model
+    from repro.optim import constant, sgd_momentum
+
+    def init(key):
+        return {n: jax.random.normal(jax.random.fold_in(key, i), s) * 0.02
+                for i, (n, s) in enumerate(_MLP.items())}
+
+    def loss_fn(params, batch, rng=None, unroll=False):
+        bf = jnp.bfloat16
+        h = jax.nn.relu(batch["x"].astype(bf) @ params["w1"].astype(bf)
+                        + params["b1"].astype(bf))
+        loss = jnp.mean(jnp.square((h @ params["w2"].astype(bf)).astype(
+            jnp.float32)))
+        return loss, {"loss": loss, "aux": jnp.zeros(())}
+
+    model = Model(cfg=None, init=init, loss_fn=loss_fn, forward=None)
+    opt = sgd_momentum(momentum=0.9, weight_decay=5e-4)
+    mesh = make_mesh((1,), ("data",), devices=topo.devices[:1])
+    host_mesh = make_mesh((1,), ("data",))
+    with jax.set_mesh(host_mesh):   # the plan and shapes come from the host
+        state = jax.eval_shape(lambda: init_sharded_train_state(
+            model, opt, jax.random.key(0), host_mesh))
+        step = make_bsp_step(model, opt, get_exchanger("asa16"),
+                             constant(0.01), mesh, microbatches=4,
+                             overlap="buckets", fuse_rs_update=True)
+    rep = NamedSharding(mesh, P())
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        (state, {"x": jax.ShapeDtypeStruct((128, 1024), jnp.float32)},
+         jax.eval_shape(lambda: jax.random.key(0))))
+    with jax.set_mesh(mesh):
+        txt = jax.jit(step).lower(*args).compile().as_text()
+
+    # a 1-D leaf's flat and shaped views are one layout: only the dense
+    # leaves can be relaid out
+    sizes = {int(np.prod(s)) for s in _MLP.values() if len(s) > 1}
+    comps = _computations(txt)
+    bodies = re.findall(r" while\(.*?body=%([\w.-]+)", txt)
+    assert len(bodies) == 1, bodies
+    in_body = [(n, op) for n, op, size in comps[bodies[0]]
+               if size in sizes and (op in ("copy", "reshape")
+                                     or _RELAYOUT.match(n))]
+    assert not in_body, in_body
+
+    # from each fused_rs_update's receive back to the accumulator's add
+    entry = re.search(r"^ENTRY %(\S+) ", txt, re.M).group(1)
+    insts = {}
+    for name, opcode, args_ in re.findall(
+            r"^\s*(?:ROOT )?%(\S+) = .*? ([\w-]+)\((.*?)\)", txt, re.M):
+        insts[name] = (opcode, re.findall(r"%([\w.-]+)", args_))
+    calls = [n for n, _, _ in comps[entry]
+             if n.startswith("fused_rs_update") and
+             insts[n][0] == "custom-call"]
+    assert len(calls) == len(_MLP), calls
+    for call in calls:
+        name, relayouts = insts[call][1][0], 0
+        while (insts[name][0] in ("bitcast", "copy", "reshape")
+               or _RELAYOUT.match(name)):
+            relayouts += insts[name][0] != "bitcast"
+            name = insts[name][1][0]
+        assert relayouts <= 1, (call, relayouts)
